@@ -400,7 +400,7 @@ def bench_runtime_detection_scale() -> List[Row]:
 
     # backend-labelled ingest rows: the same chunk through the fused
     # single-pass host bincount (the CPU production path behind
-    # ``ingest_batch``) and the Pallas scatter-add histogram kernel in
+    # ``ingest_batch``) and the Pallas histogram kernel in
     # interpret mode (the accelerator path; interpret wall clock tracks
     # the trajectory, it is not a device projection)
     import jax.numpy as jnp

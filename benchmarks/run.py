@@ -29,6 +29,8 @@ def main() -> None:
     args = ap.parse_args()
     args.no_kernels = args.no_kernels or args.quick
 
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     if args.metrics_out:
         from repro import obs
         obs.enable()

@@ -12,6 +12,7 @@ from repro.core.capacity import RegionCapacity
 from repro.core.overcommit_sim import OvercommitSimConfig, recommend_factor
 from repro.core.service import synthesize_fleet
 from repro.core.tiers import o_max
+from repro.launch.compile_cache import enable_compile_cache
 
 
 def main():
@@ -45,4 +46,5 @@ def main():
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

@@ -27,6 +27,7 @@ import numpy as np
 from repro.chaos import campaign_for_fleet, sample_faults, verify_report
 from repro.core.service import synthesize_fleet
 from repro.graph import CallGraph, plan_hardening
+from repro.launch.compile_cache import enable_compile_cache
 
 
 def main():
@@ -148,4 +149,5 @@ def _knob_of(family: str) -> str:
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

@@ -28,6 +28,7 @@ from repro.core.scenarios import (FleetAggregates, summarize_sweep,
 from repro.core.service import synthesize_fleet, unsafe_edges
 from repro.core.tiers import Tier
 from repro.data import SyntheticLMDataset, make_train_iterator
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import LMConfig, init_params
 from repro.serving import Request, ServingEngine, TieredScheduler
 from repro.train import make_train_state, make_train_step
@@ -135,4 +136,5 @@ def main():
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

@@ -28,6 +28,7 @@ from repro.core.service import synthesize_fleet, unsafe_edges
 from repro.core.static_analysis import static_analysis
 from repro.graph import (CallGraph, blackhole_ensemble, certify,
                          plan_hardening, regression_gate)
+from repro.launch.compile_cache import enable_compile_cache
 
 SCALE = 0.15          # detection runs on the object fleet (IR + traces)
 SEED = 7
@@ -152,4 +153,5 @@ def main():
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

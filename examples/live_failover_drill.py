@@ -27,6 +27,7 @@ import time
 from repro import obs
 from repro.chaos import verify_report
 from repro.core.tiers import FailureClass, RTO_SECONDS
+from repro.launch.compile_cache import enable_compile_cache
 from repro.serving import DrillSpec, drill_oracle, request_campaign, run_drill
 
 
@@ -77,6 +78,7 @@ def main(smoke: bool = False):
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--smoke", action="store_true",
                     help="drill + SLA asserts only (CI-sized)")

@@ -19,6 +19,7 @@ import numpy as np
 
 from repro.core.service import synthesize_fleet
 from repro.graph import CallGraph, plan_hardening
+from repro.launch.compile_cache import enable_compile_cache
 from repro.optim import hardening_weights, optimize_capacity
 
 
@@ -66,6 +67,7 @@ def main(smoke: bool = False):
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--smoke", action="store_true",
                     help="small fleet + tiny budgets (CI gate)")

@@ -8,6 +8,7 @@ import tempfile
 import jax
 
 from repro.data import SyntheticLMDataset, make_train_iterator
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import LMConfig
 from repro.optim import cosine_schedule, make_optimizer
 from repro.train import make_train_state, make_train_step
@@ -37,4 +38,5 @@ def main():
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

@@ -16,6 +16,7 @@ import numpy as np
 
 from repro.configs import get_arch
 from repro.core.tiers import Tier
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import init_params
 from repro.serving import Request, ServingEngine, TieredScheduler
 
@@ -80,4 +81,5 @@ def main():
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

@@ -19,7 +19,6 @@ cloud draw, alongside the analytic closed-form verdicts.
 
 import argparse
 import os
-import subprocess
 import sys
 import time
 from contextlib import nullcontext
@@ -33,6 +32,8 @@ from repro.core.service import synthesize_fleet
 from repro.core.sweep_engine import tile_grid
 from repro.core.tiers import Tier
 from repro.graph import CallGraph, plan_hardening
+from repro.launch.compile_cache import enable_compile_cache
+from repro.launch.mesh import take_devices
 
 
 def main():
@@ -41,8 +42,9 @@ def main():
                     help="scenario count (the 256-point base grid is "
                          "tiled out; the fused engine bucket-pads)")
     ap.add_argument("--devices", type=int, default=1,
-                    help="virtual host devices to shard the scenario "
-                         "axis over (re-executes under XLA_FLAGS)")
+                    help="devices to shard the scenario axis over (on "
+                         "CPU: virtual host devices, re-executes under "
+                         "XLA_FLAGS)")
     ap.add_argument("--trace", nargs="?", const="failover_trace.json",
                     default=None, metavar="PATH",
                     help="write a Chrome trace-event JSON of the run "
@@ -53,14 +55,7 @@ def main():
                     help="enable the metrics registry and write a "
                          "Prometheus snapshot (+ JSONL next to it)")
     args = ap.parse_args()
-    if args.devices > 1 and "_TEMPORAL_SWEEP_CHILD" not in os.environ:
-        env = dict(os.environ, _TEMPORAL_SWEEP_CHILD="1")
-        env["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "") +
-                            f" --xla_force_host_platform_device_count="
-                            f"{args.devices}").strip()
-        env.setdefault("PYTHONPATH", "src")
-        raise SystemExit(subprocess.run(
-            [sys.executable, *sys.argv], env=env).returncode)
+    devices = take_devices(args.devices, sys.argv)
 
     tracer, prof = None, None
     if args.trace or args.metrics_out:
@@ -78,17 +73,17 @@ def main():
     fs = synthesize_fleet(scale=0.1, seed=7, as_arrays=True,
                           unsafe_chain_fraction=0.02)
     fs.apply_ufa_target_classes()
-    import jax
     print(f"fleet: {fs.n} service-environments, "
           f"{float(fs.spec_cores.sum()):,.0f} cores | "
-          f"grid={args.grid_size} devices={len(jax.devices())}")
+          f"grid={args.grid_size} devices={len(devices)}")
 
     grid = tile_grid(scenario_grid(), args.grid_size)
 
     # 1. the un-remediated fleet: fail-close chains break criticals in
     #    every blackhole scenario, sinking the availability trace
     with phase("sweep-unhardened"):
-        res0 = sweep_with_dependency_ensemble(fs, grid=grid, temporal=True)
+        res0 = sweep_with_dependency_ensemble(fs, grid=grid, temporal=True,
+                                              devices=devices)
     print(f"\nbefore hardening: t_sla_ok="
           f"{int(res0['t_sla_ok'].sum())}/{len(res0['t_sla_ok'])} "
           f"worst avail integral "
@@ -108,7 +103,8 @@ def main():
     #    warm after step 1 compiled the bucket)
     t0 = time.time()
     with phase("sweep-hardened"):
-        res = sweep_with_dependency_ensemble(fs, grid=grid, temporal=True)
+        res = sweep_with_dependency_ensemble(fs, grid=grid, temporal=True,
+                                             devices=devices)
     dt = time.time() - t0
     print(f"fused sweep: {len(res['sla_ok'])} scenarios in {dt:.2f}s "
           f"({len(res['sla_ok'])/dt:,.0f} scenarios/s)")
@@ -203,4 +199,5 @@ def main():
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

@@ -389,7 +389,8 @@ def sweep_with_dependency_ensemble(fs: FleetState,
                                    seed: int = 0,
                                    temporal: bool = False,
                                    region: Optional[object] = None,
-                                   ts: Optional[np.ndarray] = None
+                                   ts: Optional[np.ndarray] = None,
+                                   devices: Optional[object] = None
                                    ) -> Dict[str, np.ndarray]:
     """Scenario sweep with the dependency layer closed in: each scenario's
     ``evict_fraction`` sets its blackhole intensity — that fraction of
@@ -405,7 +406,7 @@ def sweep_with_dependency_ensemble(fs: FleetState,
     and folds the same propagation verdicts into the availability
     *trace*: a broken critical's penalty decays as its dark dependencies
     restore, and the ``t_``-prefixed temporal verdicts land next to the
-    analytic ones."""
+    analytic ones; ``devices`` goes to the engine (see ``SweepEngine``)."""
     from repro.graph import CallGraph
     grid = grid if grid is not None else scenario_grid()
     graph = CallGraph.from_fleet_state(fs)
@@ -425,7 +426,8 @@ def sweep_with_dependency_ensemble(fs: FleetState,
         from repro.core.timeline_sim import config_for_fleet
         timeline = config_for_fleet(fs, region=region)
         eng = SweepEngine(agg, timeline, graph=graph,
-                          seed=stage_seed(seed, "sweep-engine"), ts=ts)
+                          seed=stage_seed(seed, "sweep-engine"), ts=ts,
+                          devices=devices)
         return eng.run(grid)
     from repro.graph import blackhole_ensemble
     ens = blackhole_ensemble(graph, seed=stage_seed(seed, "blackhole-ensemble"),

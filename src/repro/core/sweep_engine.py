@@ -24,9 +24,10 @@ three stages into ONE jitted, device-parallel pipeline:
     counts are padded to powers of two, so grids from 256 to 100k+
     scenarios reuse a handful of compiled shapes (no recompile per size
     within a padding bucket; see ``bucket_shape`` / ``compiled_variants``);
-  * the chunk axis is sharded across devices via ``repro.dist``
-    (``ctx.sharding_rules`` + a ``NamedSharding`` over a 1-D "scenarios"
-    mesh), and the scenario buffers are donated to the pipeline.
+  * the scenario width is split across devices (a ``NamedSharding``
+    over a 1-D "scenarios" mesh, and ``shard_map`` so that each device
+    runs the whole pipeline on its own slice), and the scenario buffers
+    are donated to the pipeline.
 
 Config (fleet aggregates, timeline constants, graph edges) is precomputed
 once into device-resident arrays and passed as *traced* arguments, so the
@@ -42,7 +43,6 @@ not.
 from __future__ import annotations
 
 import time
-from contextlib import nullcontext
 from functools import partial
 from typing import Dict, Optional
 
@@ -62,7 +62,7 @@ from repro.core.timeline_sim import (PARAM_KEYS, TimelineConfig,
                                      timeline_verdicts,
                                      timeline_verdicts_batch,
                                      validate_grid)
-from repro.dist import ctx as dist_ctx
+from repro.dist.smap import shard_map
 from repro.kernels import backend as _kbackend
 
 # mega-batch width for lax.map chunking: big enough to amortize scan-step
@@ -122,26 +122,42 @@ def _fused_verdicts_block(consts: Dict, p: Dict, ts, temporal: bool,
         lambda q: _fused_verdicts(consts, q, ts, temporal, tau))(p)
 
 
-@partial(jax.jit, static_argnames=("temporal", "reducer"),
+_WIDE = P(None, "scenarios")          # (n_chunks, width): split the width
+
+
+def _per_device(fn, mesh, in_specs):
+    """Run ``fn`` once per device of the 1-D scenario ``mesh`` on that
+    device's slice of the scenario width (``shard_map``; ``in_specs``
+    marks the ``(n_chunks, width)`` arguments ``_WIDE``, the rest ``P()``).
+    Every verdict is per scenario, so the body needs no collectives, and
+    each device runs the Pallas reducer on its own slice.  ``mesh=None``
+    runs ``fn`` as it is."""
+    if mesh is None:
+        return fn
+    return shard_map(fn, mesh=mesh, in_specs=in_specs, out_specs=_WIDE)
+
+
+@partial(jax.jit, static_argnames=("temporal", "reducer", "mesh"),
          donate_argnums=(1,))
 def _run_chunks(consts, pchunks, ts, tau=None, *, temporal,
-                reducer="scan"):
+                reducer="scan", mesh=None):
     """Fused pipeline, explicit ``dep_broken_frac``: lax.map over
     ``(n_chunks, width)`` scenario mega-batches of the fused scenario
     block function.  ``tau=None`` vs a traced scalar hit different jit
     cache entries (different pytree structures), so the hard path's
     compiled program is untouched by soft runs."""
-    def one(p):
-        p = dict(p, dep_broken_frac=dist_ctx.hint(p["dep_broken_frac"],
-                                                  "batch"))
-        return _fused_verdicts_block(consts, p, ts, temporal, reducer, tau)
-    return lax.map(one, pchunks)
+    def body(consts, pchunks, ts, tau):
+        return lax.map(lambda p: _fused_verdicts_block(
+            consts, p, ts, temporal, reducer, tau), pchunks)
+    return _per_device(body, mesh, (P(), _WIDE, P(), P()))(
+        consts, pchunks, ts, tau)
 
 
-@partial(jax.jit, static_argnames=("temporal", "reducer"),
+@partial(jax.jit, static_argnames=("temporal", "reducer", "mesh"),
          donate_argnums=(2, 3, 4))
 def _run_chunks_dep(consts, dep, pchunks, invchunks, storm_invchunks,
-                    dark_u, ts, tau=None, *, temporal, reducer="scan"):
+                    dark_u, ts, tau=None, *, temporal, reducer="scan",
+                    mesh=None):
     """Fused pipeline with the dependency stage in-program: propagate the
     (U, n) unique dark sets to their fixed point (backend-dispatched —
     the Pallas ELL kernel when ``dep`` carries the ELL adjacency), then
@@ -150,19 +166,28 @@ def _run_chunks_dep(consts, dep, pchunks, invchunks, storm_invchunks,
     and the availability model.  ``dark_u`` carries the blackhole uniques
     AND the cascade-storm uniques (``combined_dark_uniques``): one
     while_loop settles both stages, and each scenario gathers its storm
-    verdict (``storm_broken_frac``) by its second index."""
+    verdict (``storm_broken_frac``) by its second index.  Sharded, every
+    device settles the (few) unique dark sets itself and gathers for its
+    own scenarios."""
     from repro.graph.propagation import broken_critical_fractions
-    counts, frac, n_dark = broken_critical_fractions(dark_u, dep)
 
-    def one(args):
-        p, inv, sinv = args
-        p = dict(p, dep_broken_frac=dist_ctx.hint(frac[inv], "batch"),
-                 storm_broken_frac=dist_ctx.hint(frac[sinv], "batch"))
-        out = _fused_verdicts_block(consts, p, ts, temporal, reducer, tau)
-        out["dep_n_broken_critical"] = counts[inv]
-        out["dep_n_dark"] = n_dark[inv]
-        return out
-    return lax.map(one, (pchunks, invchunks, storm_invchunks))
+    def body(consts, dep, pchunks, invchunks, storm_invchunks, dark_u, ts,
+             tau):
+        counts, frac, n_dark = broken_critical_fractions(dark_u, dep)
+
+        def one(args):
+            p, inv, sinv = args
+            p = dict(p, dep_broken_frac=frac[inv],
+                     storm_broken_frac=frac[sinv])
+            out = _fused_verdicts_block(consts, p, ts, temporal, reducer,
+                                        tau)
+            out["dep_n_broken_critical"] = counts[inv]
+            out["dep_n_dark"] = n_dark[inv]
+            return out
+        return lax.map(one, (pchunks, invchunks, storm_invchunks))
+    specs = (P(), P(), _WIDE, _WIDE, _WIDE, P(), P(), P())
+    return _per_device(body, mesh, specs)(consts, dep, pchunks, invchunks,
+                                          storm_invchunks, dark_u, ts, tau)
 
 
 def compiled_variants() -> int:
@@ -281,8 +306,43 @@ class SweepEngine:
         sharding is off for this run)."""
         if not shard:
             return tree
-        return jax.device_put(
-            tree, NamedSharding(self.mesh, P(None, "scenarios")))
+        return jax.device_put(tree, NamedSharding(self.mesh, _WIDE))
+
+    def _pipeline(self, grid: Dict[str, np.ndarray],
+                  dep_broken_frac: Optional[np.ndarray] = None,
+                  temporal: bool = True, tau=None):
+        """The jitted pipeline ``run`` calls for ``grid``, with its
+        arguments: ``(fn, args, kwargs)``."""
+        n = validate_grid(grid)
+        shape = bucket_shape(n, self.chunk)
+        params = self._params(grid, n, shape)
+        shard = self._shard_for(shape)
+        kw = dict(temporal=temporal,
+                  reducer=self.reducer if tau is None else "scan",
+                  mesh=self.mesh if shard else None)
+        if self.graph is not None and dep_broken_frac is None:
+            from repro.graph.propagation import combined_dark_uniques
+            fractions = (np.asarray(grid["evict_fraction"])
+                         if "evict_fraction" in grid else np.ones(n))
+            storm_fr = (np.asarray(grid["storm_refrac"])
+                        if "storm_refrac" in grid else None)
+            dark_u, inv, storm_inv = combined_dark_uniques(
+                self.graph, fractions, storm_fr,
+                seed=self.seed, storm_seed=self.storm_seed)
+            return _run_chunks_dep, (
+                self.consts, self.dep, self._put(params, shard),
+                self._put(self._chunked(inv, shape), shard),
+                self._put(self._chunked(storm_inv, shape), shard),
+                jnp.asarray(dark_u), self._ts_dev, tau), kw
+        frac = (np.zeros(n, np.float32) if dep_broken_frac is None
+                else np.asarray(dep_broken_frac, np.float32))
+        params["dep_broken_frac"] = self._chunked(frac, shape)
+        sfrac = (np.asarray(grid["storm_broken_frac"], np.float32)
+                 if "storm_broken_frac" in grid
+                 else np.zeros(n, np.float32))
+        params["storm_broken_frac"] = self._chunked(sfrac, shape)
+        return _run_chunks, (self.consts, self._put(params, shard),
+                             self._ts_dev, tau), kw
 
     # ------------------------------------------------------------------
     def dep_fractions(self, fractions: np.ndarray
@@ -340,7 +400,6 @@ class SweepEngine:
         n = validate_grid(grid)
         tau = (None if soft_tau is None
                else jnp.asarray(soft_tau, jnp.float32))
-        reducer = self.reducer if tau is None else "scan"
         shape = bucket_shape(n, self.chunk)
         # one enabled() branch per run() call — free off (and the result
         # below is host-materialized, so the interior timing is honest)
@@ -348,42 +407,8 @@ class SweepEngine:
         if meter:
             t0 = time.perf_counter()
             variants0 = compiled_variants()
-        params = self._params(grid, n, shape)
-        use_dep = self.graph is not None and dep_broken_frac is None
-        shard = self._shard_for(shape)
-
-        rules = {"batch": "scenarios"}
-        cm = (dist_ctx.sharding_rules(self.mesh, rules)
-              if shard else nullcontext())
-        with cm:
-            if use_dep:
-                from repro.graph.propagation import combined_dark_uniques
-                fractions = (np.asarray(grid["evict_fraction"])
-                             if "evict_fraction" in grid
-                             else np.ones(n))
-                storm_fr = (np.asarray(grid["storm_refrac"])
-                            if "storm_refrac" in grid else None)
-                dark_u, inv, storm_inv = combined_dark_uniques(
-                    self.graph, fractions, storm_fr,
-                    seed=self.seed, storm_seed=self.storm_seed)
-                out = _run_chunks_dep(
-                    self.consts, self.dep,
-                    self._put(params, shard),
-                    self._put(self._chunked(inv, shape), shard),
-                    self._put(self._chunked(storm_inv, shape), shard),
-                    jnp.asarray(dark_u), self._ts_dev, tau,
-                    temporal=temporal, reducer=reducer)
-            else:
-                frac = (np.zeros(n, np.float32) if dep_broken_frac is None
-                        else np.asarray(dep_broken_frac, np.float32))
-                params["dep_broken_frac"] = self._chunked(frac, shape)
-                sfrac = (np.asarray(grid["storm_broken_frac"], np.float32)
-                         if "storm_broken_frac" in grid
-                         else np.zeros(n, np.float32))
-                params["storm_broken_frac"] = self._chunked(sfrac, shape)
-                out = _run_chunks(self.consts, self._put(params, shard),
-                                  self._ts_dev, tau, temporal=temporal,
-                                  reducer=reducer)
+        fn, args, kw = self._pipeline(grid, dep_broken_frac, temporal, tau)
+        out = fn(*args, **kw)
 
         result = {k: np.asarray(v).reshape(-1, *v.shape[2:])[:n]
                   for k, v in out.items()}

@@ -14,7 +14,7 @@ from contextlib import contextmanager
 from typing import Dict, Optional, Tuple, Union
 
 import jax
-from jax.sharding import NamedSharding, PartitionSpec as P
+from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec as P
 
 Rule = Union[None, str, Tuple[str, ...]]
 
@@ -39,6 +39,18 @@ def default_rules(mesh) -> Dict[str, Rule]:
     }
 
 
+def auto_axes(mesh: Mesh) -> Mesh:
+    """``mesh`` with every axis ``Auto``.  The annotations and sharding
+    tables of this package are placement hints that the compiler
+    propagates; ``jax.make_mesh`` builds ``Explicit`` axes, under which
+    every op that mixes differently sharded operands must be told its
+    output sharding."""
+    if all(t == AxisType.Auto for t in mesh.axis_types):
+        return mesh
+    return Mesh(mesh.devices, mesh.axis_names,
+                axis_types=(AxisType.Auto,) * len(mesh.axis_names))
+
+
 class _Ctx:
     __slots__ = ("mesh", "rules")
 
@@ -54,7 +66,7 @@ def sharding_rules(mesh, rules: Optional[Dict[str, Rule]] = None):
     if rules:
         merged.update(rules)
     prev = getattr(_TLS, "ctx", None)
-    _TLS.ctx = _Ctx(mesh, merged)
+    _TLS.ctx = _Ctx(auto_axes(mesh), merged)
     try:
         yield _TLS.ctx
     finally:
@@ -98,11 +110,15 @@ def axis_size(name: str) -> int:
 def hint(x, *logical_axes):
     """Annotate ``x`` with a sharding constraint derived from logical axis
     names (one per dimension, ``None`` = replicated).  Identity when no
-    context is active, on 1-sized mappings, and on non-divisible dims."""
+    context is active, inside ``shard_map``, on 1-sized mappings, and on
+    non-divisible dims."""
     ctx = _current()
     if ctx is None:
         return x
     mesh = ctx.mesh
+    if jax.sharding.get_abstract_mesh().manual_axes:
+        # inside a shard_map body the caller already owns the layout
+        return x
     spec = []
     pinned = False
     for dim, name in zip(x.shape, logical_axes):
@@ -118,10 +134,4 @@ def hint(x, *logical_axes):
     if not pinned:
         return x
     spec += [None] * (x.ndim - len(spec))
-    try:
-        return jax.lax.with_sharding_constraint(
-            x, NamedSharding(mesh, P(*spec)))
-    except Exception:
-        # inside shard_map bodies (or other manual regions) constraints
-        # don't apply — the caller already owns the layout
-        return x
+    return jax.lax.with_sharding_constraint(x, NamedSharding(mesh, P(*spec)))

@@ -15,6 +15,8 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
+from repro.dist.ctx import auto_axes
+
 
 def batch_axes(mesh) -> Tuple[str, ...]:
     """Mesh axes a global batch dimension spreads over."""
@@ -22,7 +24,7 @@ def batch_axes(mesh) -> Tuple[str, ...]:
 
 
 def replicated(mesh) -> NamedSharding:
-    return NamedSharding(mesh, P())
+    return NamedSharding(auto_axes(mesh), P())
 
 
 def cache_seq_len(seq_len: int, pad: int = 256) -> int:
@@ -87,6 +89,7 @@ def _leaf_spec(path, leaf, mesh, fsdp: bool):
 
 def param_shardings(cfg, mesh, fsdp: bool = True):
     """NamedSharding pytree matching ``init_params(cfg, key)``."""
+    mesh = auto_axes(mesh)
     from repro.models import init_params
     abstract = jax.eval_shape(lambda k: init_params(cfg, k),
                               jax.random.PRNGKey(0))
@@ -98,6 +101,7 @@ def param_shardings(cfg, mesh, fsdp: bool = True):
 
 def train_batch_shardings(cfg, mesh):
     """Shardings for {"inputs", "labels"} train batches (batch-dim DP)."""
+    mesh = auto_axes(mesh)
     def shard(ndim_tail):
         return NamedSharding(mesh, P(batch_axes(mesh) or None,
                                      *([None] * ndim_tail)))
@@ -110,6 +114,7 @@ def prefill_shardings(cfg, mesh):
 
 
 def decode_token_shardings(cfg, mesh, batch: int):
+    mesh = auto_axes(mesh)
     spec = _batch_spec(mesh, batch)
     if cfg.embed_inputs:
         return NamedSharding(mesh, P(spec))
@@ -120,6 +125,7 @@ def decode_state_shardings(cfg, mesh, batch: int):
     """DecodeState shardings: KV caches shard their sequence dim over
     "model" (split-KV decode), batch dims over the data axes."""
     from repro.models.model import DecodeState, init_decode_state
+    mesh = auto_axes(mesh)
     abstract = jax.eval_shape(
         lambda: init_decode_state(cfg, batch, 8, jnp.bfloat16))
 

@@ -1,23 +1,15 @@
-"""shard_map wrapper (API drift shim).
+"""shard_map wrapper.
 
 Collective-heavy code (MoE expert parallel, split-KV decode, pipeline)
-goes through here so jax version drift is absorbed in one place.
-``check_rep=False`` by default: our bodies mix psum/pmax merges whose
-replication typing the checker rejects on some versions.
+goes through here.  The replication check is off: our bodies mix
+psum/pmax merges whose replication typing the checker rejects.
 """
 
 from __future__ import annotations
 
-try:                                    # jax >= 0.4.31 experimental API
-    from jax.experimental.shard_map import shard_map as _shard_map
-except ImportError:                     # newer jax: promoted to jax.shard_map
-    from jax import shard_map as _shard_map
+import jax
 
 
-def shard_map(f, *, mesh, in_specs, out_specs, check_rep: bool = False):
-    try:
-        return _shard_map(f, mesh=mesh, in_specs=in_specs,
-                          out_specs=out_specs, check_rep=check_rep)
-    except TypeError:                   # check_rep removed upstream
-        return _shard_map(f, mesh=mesh, in_specs=in_specs,
-                          out_specs=out_specs)
+def shard_map(f, *, mesh, in_specs, out_specs):
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)

@@ -1,4 +1,4 @@
-"""Scatter-add histogram ingest Pallas kernel (telemetry hot path).
+"""Histogram ingest Pallas kernel (telemetry hot path).
 
 ``core/dependency.py`` folds ``(edge_id, callee_failed, caller_errored)``
 chunks into four per-edge count arrays.  On CPU that is a host
@@ -15,15 +15,18 @@ propagated — from which all four detector columns derive (``calls`` =
 row sum, ``callee_failures`` = col2+col3, ``errors_given_failure`` =
 col3, ``errors_given_ok`` = col1).
 
-The grid walks record blocks sequentially against the full resident
-histogram block (``pl.when`` zero-init on the first step); each step is
-a flat ``jnp`` scatter-add *by value* (``zeros.at[...].add(1)``), which
-— unlike in-kernel ``ref[idx] += 1`` — accumulates duplicate indices
-correctly in both interpret and compiled modes.  Counts are int32 per
-chunk (a 4M-record chunk cannot overflow); the caller folds chunks into
-its int64 accumulators host-side.  Padding records carry an edge id one
-past the histogram rows and are dropped by the scatter's out-of-bounds
-mode.
+Mosaic has no vector scatter, so the kernel counts one record at a
+time: the flat bin ``4 * edge_id + code`` names a row of ``LANES`` bins
+and a lane, and each record is a dynamic row load, a one-hot lane add
+and a row store.  Record keys stream through SMEM in ``block_n`` blocks
+(the inner grid axis); the histogram is lane-dense (~2 MB for the
+paper-scale ~120k edges, not the 61 MB of an ``(n_edges, 4)`` block
+padded to 128 lanes) and tiled over edges (the outer grid axis,
+``block_e`` edges per tile), with each tile resident across the record
+blocks (``pl.when`` zero-init on the first).  Counts are int32 per chunk
+(a 4M-record chunk cannot overflow); the caller folds chunks into its
+int64 accumulators host-side.  Keys outside a tile add zero, and padding
+records carry key ``-1``, outside every tile.
 """
 
 from __future__ import annotations
@@ -34,65 +37,82 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels.backend import default_interpret
 
 N_CODES = 4                      # 2-bit outcome code
+LANES = 128                      # histogram bins per vector row
+UNROLL = 8                       # records per loop iteration
 
 
-def _hist_kernel(eid_ref, code_ref, o_ref):
-    r = pl.program_id(0)
+def _hist_kernel(key_ref, o_ref):
+    """Count one SMEM block of record keys into one edge tile of the
+    lane-dense histogram (``LANES`` bins per row), one record at a time:
+    a dynamic row load, a one-hot lane add, a row store.  Keys outside
+    the tile (other tiles' records, and the ``-1`` padding) add zero."""
+    t, r = pl.program_id(0), pl.program_id(1)
 
     @pl.when(r == 0)
     def _init():
         o_ref[...] = jnp.zeros_like(o_ref)
 
-    eid = eid_ref[0]                               # (block_n,) int32
-    code = code_ref[0]
-    n_bins = o_ref.shape[0] * N_CODES
-    flat = jnp.zeros((n_bins,), jnp.int32).at[
-        eid * N_CODES + code].add(1, mode="drop")
-    o_ref[...] += flat.reshape(o_ref.shape)
+    tile_bins = o_ref.shape[0] * LANES
+    lane = jax.lax.broadcasted_iota(jnp.int32, (1, LANES), 1)
+
+    def records(i, carry):
+        for j in range(UNROLL):
+            local = key_ref[i * UNROLL + j] - t * tile_bins
+            hit = (local >= 0) & (local < tile_bins)
+            local = jnp.where(hit, local, 0)
+            o_ref[pl.ds(local // LANES, 1), :] += (
+                (lane == local % LANES) & hit).astype(jnp.int32)
+        return carry
+
+    jax.lax.fori_loop(0, key_ref.shape[0] // UNROLL, records, 0)
 
 
 @functools.partial(jax.jit,
-                   static_argnames=("n_edges", "block_n", "interpret"))
+                   static_argnames=("n_edges", "block_n", "block_e",
+                                    "interpret"))
 def ingest_hist(edge_id: jnp.ndarray, callee_failed: jnp.ndarray,
                 caller_errored: jnp.ndarray, n_edges: int, *,
-                block_n: int = 262_144,
+                block_n: int = 16_384, block_e: int = 131_072,
                 interpret: Optional[bool] = None) -> jnp.ndarray:
-    """One chunk -> ``(n_edges, 4)`` int32 outcome-code histogram."""
+    """One chunk -> ``(n_edges, 4)`` int32 outcome-code histogram.
+    ``block_n`` records per grid step; ``block_e`` edges per histogram
+    tile (the paper-scale ~120k edges fit one tile)."""
     interpret = default_interpret() if interpret is None else interpret
-    eid = edge_id.astype(jnp.int32)
-    code = (callee_failed.astype(jnp.int32) * 2
-            + caller_errored.astype(jnp.int32))
-    n = eid.shape[0]
+    n = edge_id.shape[0]
     if n == 0 or n_edges == 0:
         return jnp.zeros((n_edges, N_CODES), jnp.int32)
+    key = (edge_id.astype(jnp.int32) * N_CODES
+           + callee_failed.astype(jnp.int32) * 2
+           + caller_errored.astype(jnp.int32))
 
-    block_n = min(block_n, n)
+    block_n = -(-min(block_n, n) // UNROLL) * UNROLL
     n_pad = -(-n // block_n) * block_n
-    e_pad = -(-n_edges // 8) * 8
-    # pad records point past the histogram rows: either clipped into the
-    # sliced-off row padding or dropped as out-of-bounds — never counted
-    # (a negative sentinel would WRAP, Python-style, before the bounds
-    # check and corrupt the last row)
-    eid_p = jnp.pad(eid, (0, n_pad - n),
-                    constant_values=e_pad).reshape(-1, block_n)
-    code_p = jnp.pad(code, (0, n_pad - n)).reshape(-1, block_n)
+    # histogram rows of LANES bins, tiles of whole 8-row groups
+    rows = -(-n_edges * N_CODES // LANES)
+    tile_rows = min(-(-block_e * N_CODES // (8 * LANES)) * 8,
+                    -(-rows // 8) * 8)
+    n_tiles = -(-rows // tile_rows)
+    # pad records carry key -1: outside every tile, never counted
+    key = jnp.pad(key, (0, n_pad - n), constant_values=-1)
 
     counts = pl.pallas_call(
         _hist_kernel,
-        grid=(n_pad // block_n,),
-        in_specs=[
-            pl.BlockSpec((1, block_n), lambda r: (r, 0)),
-            pl.BlockSpec((1, block_n), lambda r: (r, 0)),
-        ],
-        out_specs=pl.BlockSpec((e_pad, N_CODES), lambda r: (0, 0)),
-        out_shape=jax.ShapeDtypeStruct((e_pad, N_CODES), jnp.int32),
+        grid=(n_tiles, n_pad // block_n),
+        in_specs=[pl.BlockSpec((block_n,), lambda t, r: (r,),
+                               memory_space=pltpu.SMEM)],
+        out_specs=pl.BlockSpec((tile_rows, LANES), lambda t, r: (t, 0)),
+        out_shape=jax.ShapeDtypeStruct((n_tiles * tile_rows, LANES),
+                                       jnp.int32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
-    )(eid_p, code_p)
-    return counts[:n_edges]
+    )(key)
+    return counts.reshape(-1)[:n_edges * N_CODES].reshape(n_edges, N_CODES)
 
 
 @functools.partial(jax.jit, static_argnames=("n_edges",))

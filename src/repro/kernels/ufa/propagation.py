@@ -6,18 +6,23 @@ CPU and lowers poorly on TPU.  This kernel flips the data layout: the CSR
 adjacency is padded host-side to ELL form (every caller row gets exactly
 ``K`` callee slots, ``K`` = max out-degree rounded up; the paper-scale
 graph measures max degree 13, so K=16 wastes little), and one round
-becomes a dense blocked *gather*:
+becomes a blocked *gather*:
 
     hit[s, u] = any_k  broken[s, ell_dst[u, k]] & ell_closed[u, k]
     new[s, u] = broken[s, u] | hit[s, u]
 
-The grid tiles (scenario block, caller-row block); each step loads the
-full ``(block_s, n_pad)`` broken slab once, gathers its ``(block_s,
-block_r, K)`` callee view and reduces over the slot axis — no scatter
-anywhere, and the whole blackhole ensemble batch shares each adjacency
-block read.  A ``lax.while_loop`` with the same round counter/bound as
-the XLA path drives the kernel to the fixed point, so ``rounds`` and the
-``broken`` matrix are bit-identical to the reference (booleans: exact).
+The frontier is held transposed, as int32 ``(n_pad, s_pad)``: services
+on sublanes, scenarios on lanes, so one callee's frontier over a
+scenario block is one lane-dense row.  The grid tiles (scenario block,
+caller-row block); the ``(n_pad, block_s)`` frontier slab of a scenario
+block stays resident (one buffer) while the caller rows walk past it, and
+each caller ORs in the rows of its fail-close callees, one dynamic row
+load per ELL slot, with the slot indices and fail-close flags in SMEM —
+no scatter and no lane gather (Mosaic lowers neither), and the whole
+blackhole ensemble batch shares each adjacency block read.  A
+``lax.while_loop`` with the same round counter/bound as the XLA path
+drives the kernel to the fixed point, so ``rounds`` and the ``broken``
+matrix are bit-identical to the reference (booleans: exact).
 
 ``ref_fixed_point`` is the XLA reference (the scatter-max formulation,
 kept here so kernel tests do not depend on the graph layer); dispatch
@@ -34,6 +39,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels.backend import default_interpret
 
@@ -76,20 +82,37 @@ def ell_from_csr(n: int, indptr: np.ndarray, dst: np.ndarray,
 # ---------------------------------------------------------------------------
 
 
-def _round_kernel(b_all_ref, b_cur_ref, dst_ref, closed_ref, o_ref):
-    """One round for one (scenario block, caller-row block) tile."""
-    b = b_all_ref[...]                       # (block_s, n_pad) bool
-    idx = dst_ref[...]                       # (block_r, K) int32
-    gathered = jnp.take(b, idx.reshape(-1), axis=1).reshape(
-        b.shape[0], idx.shape[0], idx.shape[1])
-    hit = jnp.any(gathered & closed_ref[...][None, :, :], axis=-1)
-    o_ref[...] = b_cur_ref[...] | hit
+def _round_kernel(dst_ref, closed_ref, b_all_ref, b_cur_ref, o_ref):
+    """One round for one (caller-row block, scenario block) tile, in the
+    transposed layout (services on sublanes, scenarios on lanes): each
+    caller row ORs in the frontier rows of its fail-close callees, read
+    one dynamic row at a time with the ELL slot indices in SMEM."""
+    block_r = o_ref.shape[0]
+    k_slots = dst_ref.shape[0] // block_r
+
+    def caller(i, carry):
+        acc = b_cur_ref[pl.ds(i, 1), :]
+        for k in range(k_slots):
+            slot = i * k_slots + k
+            acc = acc | (b_all_ref[pl.ds(dst_ref[slot], 1), :]
+                         & closed_ref[slot])
+        o_ref[pl.ds(i, 1), :] = acc
+        return carry
+
+    jax.lax.fori_loop(0, block_r, caller, 0)
+
+
+def _vmem_limit(n_pad: int, block_s: int) -> int:
+    """Scoped VMEM for one round: the resident frontier slab (single
+    buffer) plus the double-buffered row tiles, with headroom."""
+    slab = n_pad * max(block_s, 128) * 4
+    return int(min(slab + (16 << 20), 100 << 20))
 
 
 @functools.partial(jax.jit,
                    static_argnames=("block_s", "block_r", "interpret"))
 def fixed_point_ell(dark: jnp.ndarray, ell_dst: jnp.ndarray,
-                    ell_closed: jnp.ndarray, *, block_s: int = 64,
+                    ell_closed: jnp.ndarray, *, block_s: int = 128,
                     block_r: int = 256,
                     interpret: Optional[bool] = None):
     """Batched least fixed point over the ELL adjacency:
@@ -105,24 +128,32 @@ def fixed_point_ell(dark: jnp.ndarray, ell_dst: jnp.ndarray,
         return dark, jnp.int32(1)
 
     block_s = min(block_s, S)
-    block_r = min(block_r, n)
+    block_r = min(block_r, -(-n // 8) * 8)
     s_pad = -(-S // block_s) * block_s
     n_pad = -(-n // block_r) * block_r
-    dark_p = jnp.pad(dark, ((0, s_pad - S), (0, n_pad - n)))
-    dst_p = jnp.pad(ell_dst, ((0, n_pad - n), (0, 0)))
-    closed_p = jnp.pad(ell_closed, ((0, n_pad - n), (0, 0)))
+    # int32 frontier, transposed: a callee's frontier is one lane-dense row
+    dark_t = jnp.pad(dark.T.astype(jnp.int32), ((0, n_pad - n),
+                                                (0, s_pad - S)))
+    dst_p = jnp.pad(ell_dst.astype(jnp.int32),
+                    ((0, n_pad - n), (0, 0))).reshape(-1)
+    closed_p = jnp.pad(ell_closed.astype(jnp.int32),
+                       ((0, n_pad - n), (0, 0))).reshape(-1)
 
+    slots = pl.BlockSpec((block_r * K,), lambda s, r: (r,),
+                         memory_space=pltpu.SMEM)
     one_round = pl.pallas_call(
         _round_kernel,
         grid=(s_pad // block_s, n_pad // block_r),
         in_specs=[
-            pl.BlockSpec((block_s, n_pad), lambda s, r: (s, 0)),
-            pl.BlockSpec((block_s, block_r), lambda s, r: (s, r)),
-            pl.BlockSpec((block_r, K), lambda s, r: (r, 0)),
-            pl.BlockSpec((block_r, K), lambda s, r: (r, 0)),
+            slots, slots,
+            pl.BlockSpec((n_pad, block_s), lambda s, r: (0, s),
+                         pipeline_mode=pl.Buffered(1)),
+            pl.BlockSpec((block_r, block_s), lambda s, r: (r, s)),
         ],
-        out_specs=pl.BlockSpec((block_s, block_r), lambda s, r: (s, r)),
-        out_shape=jax.ShapeDtypeStruct((s_pad, n_pad), jnp.bool_),
+        out_specs=pl.BlockSpec((block_r, block_s), lambda s, r: (r, s)),
+        out_shape=jax.ShapeDtypeStruct((n_pad, s_pad), jnp.int32),
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=_vmem_limit(n_pad, block_s)),
         interpret=interpret,
     )
 
@@ -132,12 +163,12 @@ def fixed_point_ell(dark: jnp.ndarray, ell_dst: jnp.ndarray,
 
     def body(state):
         broken, _, i = state
-        new = one_round(broken, broken, dst_p, closed_p)
+        new = one_round(dst_p, closed_p, broken, broken)
         return new, (new != broken).any(), i + 1
 
     broken, _, rounds = jax.lax.while_loop(
-        cond, body, (dark_p, jnp.bool_(True), jnp.int32(0)))
-    return broken[:S, :n], rounds
+        cond, body, (dark_t, jnp.bool_(True), jnp.int32(0)))
+    return broken[:n, :S].T != 0, rounds
 
 
 # ---------------------------------------------------------------------------

@@ -4,17 +4,22 @@
 its summary carry with a sequential ``lax.scan`` — T dependent steps per
 scenario, even though every accumulator is associative: the availability
 integral is a dot with the step widths, the floor/peaks are min/max, and
-the per-tier restore time is a first-crossing over a cumulative-OR.
+the per-tier restore time is a first crossing.
 This kernel reduces a whole scenario block at once:
 
     avail_int  = sum_t availability * dt        (dt[0] = 0, scan parity)
     avail_min  = min(1, min_t availability)
     util_peak  = max(0, max_t util_model)
     cloud_peak = max(0, max_t cloud_used)
-    below      = tier_frac < thresh             (S, T, R)
-    seen       = cumulative-OR_t below
-    restore_t  = min_t { ts[t] : seen[t] & ~below[t] }   (inf if never)
-    below_seen = seen[:, -1, :]
+    below      = tier_frac < thresh             (S, R, T)
+    first      = min_t { t : below[t] }                  (T if never)
+    restore_t  = min_t { ts[t] : t > first & ~below[t] } (inf if never)
+    below_seen = first < T
+
+The first crossing needs no cumulative-OR: ``seen[t] & ~below[t]`` of
+the scan is ``t > first & ~below[t]``, an iota comparison.  Tiers sit on
+sublanes and steps on lanes (an ``(S, T, R)`` block would pad R to 128
+lanes), and every output is a keepdims reduction over the step lanes.
 
 Min/max/first-crossing outputs are *exact* vs the scan (selections, not
 sums); ``avail_int`` is a reordered float32 sum, so parity is
@@ -39,20 +44,29 @@ from repro.kernels.backend import default_interpret
 
 
 def _reduce_kernel(a_ref, u_ref, cl_ref, fr_ref, dt_ref, ts_ref,
-                   stats_ref, restore_ref, seen_ref, *, thresh: float):
+                   int_ref, min_ref, upk_ref, cpk_ref, restore_ref,
+                   seen_ref, *, thresh: float):
     a = a_ref[...]                                     # (block_s, T)
-    stats_ref[...] = jnp.stack([
-        jnp.sum(a * dt_ref[...], axis=1),
-        jnp.minimum(jnp.min(a, axis=1), 1.0),
-        jnp.maximum(jnp.max(u_ref[...], axis=1), 0.0),
-        jnp.maximum(jnp.max(cl_ref[...], axis=1), 0.0),
-    ], axis=1)
-    below = fr_ref[...] < thresh                       # (block_s, T, R)
-    seen = jax.lax.associative_scan(jnp.logical_or, below, axis=1)
-    crossed = seen & jnp.logical_not(below)
+    int_ref[...] = jnp.sum(a * dt_ref[...], axis=1, keepdims=True)
+    min_ref[...] = jnp.minimum(jnp.min(a, axis=1, keepdims=True), 1.0)
+    upk_ref[...] = jnp.maximum(
+        jnp.max(u_ref[...], axis=1, keepdims=True), 0.0)
+    cpk_ref[...] = jnp.maximum(
+        jnp.max(cl_ref[...], axis=1, keepdims=True), 0.0)
+    below = fr_ref[...] < thresh                       # (block_s, R, T)
+    n_t = below.shape[2]
+    # first-crossing without a cumulative-OR: ``first`` is the first step
+    # below threshold (T if never), and a tier is restored at the least
+    # later step that is not below
+    step = jax.lax.broadcasted_iota(jnp.int32, below.shape, 2).astype(
+        jnp.float32)
+    first = jnp.min(jnp.where(below, step, float(n_t)), axis=2,
+                    keepdims=True)
+    crossed = (step > first) & jnp.logical_not(below)
     restore_ref[...] = jnp.min(
-        jnp.where(crossed, ts_ref[...][0][None, :, None], jnp.inf), axis=1)
-    seen_ref[...] = seen[:, -1, :]
+        jnp.where(crossed, ts_ref[...][None], jnp.inf), axis=2,
+        keepdims=True)
+    seen_ref[...] = (first < n_t).astype(jnp.int32)
 
 
 @functools.partial(jax.jit,
@@ -76,33 +90,36 @@ def timeline_reduce(avail: jnp.ndarray, util: jnp.ndarray,
     block_s = min(block_s, S)
     s_pad = -(-S // block_s) * block_s
     pad = ((0, s_pad - S), (0, 0))
-    stats, restore, seen = pl.pallas_call(
+    # tiers on sublanes, steps on lanes: an (S, T, R) block would pad R up
+    # to 128 lanes
+    frac = jnp.pad(jnp.swapaxes(tier_frac, 1, 2), (*pad, (0, 0)),
+                   constant_values=1.0)
+    row = pl.BlockSpec((block_s, 1), lambda s: (s, 0))
+    tier = pl.BlockSpec((block_s, R, 1), lambda s: (s, 0, 0))
+    f32 = jax.ShapeDtypeStruct((s_pad, 1), jnp.float32)
+    *stats, restore, seen = pl.pallas_call(
         functools.partial(_reduce_kernel, thresh=thresh),
         grid=(s_pad // block_s,),
         in_specs=[
             pl.BlockSpec((block_s, T), lambda s: (s, 0)),
             pl.BlockSpec((block_s, T), lambda s: (s, 0)),
             pl.BlockSpec((block_s, T), lambda s: (s, 0)),
-            pl.BlockSpec((block_s, T, R), lambda s: (s, 0, 0)),
+            pl.BlockSpec((block_s, R, T), lambda s: (s, 0, 0)),
             pl.BlockSpec((1, T), lambda s: (0, 0)),
             pl.BlockSpec((1, T), lambda s: (0, 0)),
         ],
-        out_specs=[
-            pl.BlockSpec((block_s, 4), lambda s: (s, 0)),
-            pl.BlockSpec((block_s, R), lambda s: (s, 0)),
-            pl.BlockSpec((block_s, R), lambda s: (s, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((s_pad, 4), jnp.float32),
-            jax.ShapeDtypeStruct((s_pad, R), jnp.float32),
-            jax.ShapeDtypeStruct((s_pad, R), jnp.bool_),
-        ],
+        out_specs=[row, row, row, row, tier, tier],
+        out_shape=[f32, f32, f32, f32,
+                   jax.ShapeDtypeStruct((s_pad, R, 1), jnp.float32),
+                   jax.ShapeDtypeStruct((s_pad, R, 1), jnp.int32)],
         interpret=interpret,
     )(jnp.pad(avail, pad), jnp.pad(util, pad), jnp.pad(cloud, pad),
-      jnp.pad(tier_frac, (*pad, (0, 0)), constant_values=1.0), dt2, ts2)
-    return {"avail_int": stats[:S, 0], "avail_min": stats[:S, 1],
-            "util_peak": stats[:S, 2], "cloud_peak": stats[:S, 3],
-            "restore_t": restore[:S], "below_seen": seen[:S]}
+      frac, dt2, ts2)
+    out = dict(zip(("avail_int", "avail_min", "util_peak", "cloud_peak"),
+                   (v[:S, 0] for v in stats)))
+    out["restore_t"] = restore[:S, :, 0]
+    out["below_seen"] = seen[:S, :, 0] != 0
+    return out
 
 
 @functools.partial(jax.jit, static_argnames=("thresh",))

@@ -5,15 +5,13 @@
       --devices 8 --mesh 4x2          # spawns with fake devices
 
 Uses the REDUCED config by default (CPU-trainable); --full selects the
-assigned full config (only sensible on real accelerators).  With
---devices > 1 the launcher re-executes itself with
-XLA_FLAGS=--xla_force_host_platform_device_count so the parent process
-keeps a single device.
+assigned full config (only sensible on real accelerators).  --devices k
+takes the first k accelerator devices in-process; on the CPU backend the
+launcher re-executes itself with
+XLA_FLAGS=--xla_force_host_platform_device_count=k.
 """
 
 import argparse
-import os
-import subprocess
 import sys
 
 
@@ -29,18 +27,15 @@ def main():
     ap.add_argument("--mesh", default="",
                     help="DxM mesh, e.g. 4x2 (defaults to devicesx1)")
     ap.add_argument("--ckpt-dir", default="/tmp/repro_ckpt")
-    ap.add_argument("--_inner", action="store_true", help=argparse.SUPPRESS)
     args = ap.parse_args()
 
-    if args.devices > 1 and not args._inner:
-        env = dict(os.environ)
-        env["XLA_FLAGS"] = (f"--xla_force_host_platform_device_count="
-                            f"{args.devices}")
-        raise SystemExit(subprocess.call(
-            [sys.executable, "-m", "repro.launch.train", "--_inner",
-             *sys.argv[1:]], env=env))
-
     import jax
+
+    from repro.launch.compile_cache import enable_compile_cache
+    from repro.launch.mesh import take_devices
+    enable_compile_cache()
+    devices = take_devices(args.devices,
+                           ["-m", "repro.launch.train", *sys.argv[1:]])
 
     from repro.configs import get_arch
     from repro.data import SyntheticLMDataset, make_train_iterator
@@ -53,7 +48,7 @@ def main():
     arch = get_arch(args.arch)
     cfg = arch.config if args.full else arch.reduced
     print(f"arch={args.arch} cfg={cfg.name} params={cfg.param_count()/1e6:.1f}M "
-          f"devices={len(jax.devices())}")
+          f"devices={len(devices)}")
 
     opt = make_optimizer(lr=cosine_schedule(3e-3, 10, args.steps))
     step_fn, _ = make_train_step(cfg, opt, n_loss_chunks=2)
@@ -61,10 +56,10 @@ def main():
     ds = SyntheticLMDataset(vocab_size=cfg.vocab_size, seq_len=args.seq,
                             global_batch=args.batch, seed=0)
 
-    if len(jax.devices()) > 1:
+    if len(devices) > 1:
         d, m = (map(int, args.mesh.split("x")) if args.mesh
-                else (len(jax.devices()), 1))
-        mesh = jax.make_mesh((d, m), ("data", "model"))
+                else (len(devices), 1))
+        mesh = jax.make_mesh((d, m), ("data", "model"), devices=devices)
         ps = param_shardings(cfg, mesh)
         state = state._replace(
             params=jax.device_put(state.params, ps),

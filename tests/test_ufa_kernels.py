@@ -177,6 +177,25 @@ def test_propagation_blocking_and_padding():
     assert int(rounds) == int(ref_rounds)
 
 
+def test_propagation_default_blocks_ragged():
+    """Default blocks with a service count that is not a multiple of
+    ``block_r`` and a scenario count past one 128-lane block: several
+    row and lane tiles, both ragged."""
+    rng = np.random.default_rng(11)
+    n, indptr, src, dst, closed = random_csr(rng, n=300, p_edge=0.01,
+                                             p_close=0.7)
+    ell_dst, ell_closed, _ = ell_from_csr(n, indptr, dst, closed)
+    dark = rng.random((130, n)) < 0.02
+    got, rounds = fixed_point_ell(
+        jnp.asarray(dark), jnp.asarray(ell_dst), jnp.asarray(ell_closed),
+        block_r=128)
+    want, ref_rounds = ref_fixed_point(
+        jnp.asarray(dark), jnp.asarray(src), jnp.asarray(dst),
+        jnp.asarray(closed))
+    assert np.array_equal(np.asarray(got), np.asarray(want))
+    assert int(rounds) == int(ref_rounds)
+
+
 def test_propagation_cycle_and_fail_open_boundary():
     # a->b, b->c, c->a all fail-close (a cycle), c->d fail-close,
     # b->e fail-OPEN; darkening d breaks the whole cycle but spares e
@@ -301,6 +320,21 @@ def test_ingest_hist_exact(n_records, n_edges, block_n):
     assert got.sum() == n_records          # pads never counted
 
 
+@pytest.mark.parametrize("n_records,n_edges,block_n,block_e", [
+    (10_000, 1100, 4096, 256),   # 5 edge tiles, the last one ragged
+    (3_000, 256, 1024, 256),     # exactly one tile
+    (999, 1000, 100, 96),        # tile rounded up to whole row groups;
+                                 # block_n rounded up to the unroll
+])
+def test_ingest_hist_edge_tiles(n_records, n_edges, block_n, block_e):
+    rng = np.random.default_rng(n_edges)
+    eid, failed, errored = _random_records(rng, n_records, n_edges)
+    got = np.asarray(ingest_hist(
+        jnp.asarray(eid), jnp.asarray(failed), jnp.asarray(errored),
+        n_edges, block_n=block_n, block_e=block_e))
+    assert np.array_equal(got, _np_hist(eid, failed, errored, n_edges))
+
+
 def test_ingest_hist_empty():
     z = jnp.zeros(0, jnp.int32)
     assert np.asarray(ingest_hist(z, z, z, 16)).sum() == 0
@@ -406,6 +440,31 @@ def test_timeline_reduce_crossing_semantics():
         jnp.ones((2, 1)), jnp.zeros((2, 1)), jnp.zeros((2, 1)),
         jnp.ones((2, 1, 3)), jnp.asarray(ts[:1]), thresh=0.999)
     assert np.asarray(one["avail_int"]).tolist() == [0.0, 0.0]
+
+
+def test_timeline_reduce_crossing_at_edges():
+    """Crossings at the first and last steps, per tier: below at t=0 and
+    restored at t=1; below from t=0 on, never restored; below only at
+    the last step; restored only at the last step; never below."""
+    ts = np.arange(5, dtype=np.float32) * 60.0
+    frac = np.ones((1, 5, 5), np.float32)
+    frac[0, 0, 0] = 0.5
+    frac[0, :, 1] = 0.5
+    frac[0, 4, 2] = 0.5
+    frac[0, :4, 3] = 0.5
+    z = np.zeros((1, 5), np.float32)
+    args = [jnp.asarray(z)] * 3 + [jnp.asarray(frac), jnp.asarray(ts)]
+    got = {k: np.asarray(v) for k, v in timeline_reduce(
+        *args, thresh=0.999).items()}
+    ref = {k: np.asarray(v) for k, v in ref_timeline_reduce(
+        *args, thresh=0.999).items()}
+    want = scalar_reduce(z, z, z, frac, ts, 0.999)
+    assert got["restore_t"][0].tolist() == [60.0, np.inf, np.inf, 240.0,
+                                            np.inf]
+    assert got["below_seen"][0].tolist() == [True, True, True, True, False]
+    for k in want:
+        assert np.array_equal(got[k], want[k]), k
+        assert np.array_equal(ref[k], want[k]), k
 
 
 @pytest.fixture(scope="module")
