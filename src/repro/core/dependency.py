@@ -253,8 +253,10 @@ def _iter_trace_chunks(edges: TraceEdges, n_records: int, seed: int,
         if n <= 0:
             break
         done += n
-        yield _sample_kernel(keys[k], n, prob, alias, unsafe,
-                             t_fail, t_prop, ambient_caller_error)
+        with obs.span("ufa.detect.sample", records=n):
+            chunk = _sample_kernel(keys[k], n, prob, alias, unsafe,
+                                   t_fail, t_prop, ambient_caller_error)
+        yield chunk
 
 
 def sample_traces(edges: TraceEdges, n_records: int, seed: int = 0,
@@ -417,24 +419,26 @@ class RuntimeFailCloseDetector:
         # one enabled() branch per multi-million-record chunk — free off
         meter = obs.enabled()
         t0 = time.perf_counter() if meter else 0.0
-        if n and _use_ufa_kernels():
-            backend = "pallas"
-            from repro.kernels.ufa.ingest import ingest_hist
-            counts = np.asarray(
-                ingest_hist(jnp.asarray(edge_id), jnp.asarray(callee_failed),
-                            jnp.asarray(caller_errored), n), np.int64)
-        else:
-            backend = "numpy"
-            eid = np.asarray(edge_id)
-            code = ((np.asarray(callee_failed, np.uint8) << 1)
-                    | np.asarray(caller_errored, np.uint8))
-            key_t = np.int64 if 4 * n >= (1 << 31) else np.int32
-            counts = np.bincount(eid.astype(key_t) * 4 + code,
-                                 minlength=4 * n).reshape(-1, 4)
-        self.calls += counts.sum(axis=1)
-        self.callee_failures += counts[:, 2] + counts[:, 3]
-        self.errors_given_failure += counts[:, 3]
-        self.errors_given_ok += counts[:, 1]
+        with obs.span("ufa.detect.ingest", records=len(edge_id)):
+            if n and _use_ufa_kernels():
+                backend = "pallas"
+                from repro.kernels.ufa.ingest import ingest_hist
+                counts = np.asarray(
+                    ingest_hist(jnp.asarray(edge_id),
+                                jnp.asarray(callee_failed),
+                                jnp.asarray(caller_errored), n), np.int64)
+            else:
+                backend = "numpy"
+                eid = np.asarray(edge_id)
+                code = ((np.asarray(callee_failed, np.uint8) << 1)
+                        | np.asarray(caller_errored, np.uint8))
+                key_t = np.int64 if 4 * n >= (1 << 31) else np.int32
+                counts = np.bincount(eid.astype(key_t) * 4 + code,
+                                     minlength=4 * n).reshape(-1, 4)
+            self.calls += counts.sum(axis=1)
+            self.callee_failures += counts[:, 2] + counts[:, 3]
+            self.errors_given_failure += counts[:, 3]
+            self.errors_given_ok += counts[:, 1]
         # int64 headroom guard: far before wraparound could corrupt the
         # evidence (2^62 calls on one edge is ~70k years of the paper's
         # 62T RPCs/week), fail loudly instead
@@ -520,9 +524,21 @@ def runtime_analysis(fleet: Union[Dict[str, ServiceSpec], FleetState],
     ``FleetState`` the detection graph is built straight from the edge
     mask (no per-edge Python objects anywhere).
     """
+    with obs.span("ufa.detect.run"):
+        return _runtime_analysis(fleet, n_records, seed, chunk_records)
+
+
+def _runtime_analysis(fleet, n_records, seed, chunk_records):
     from repro.graph import CallGraph
 
-    edges = trace_edges(fleet, seed=seed)
+    with obs.span("ufa.detect.tables") as tables:
+        edges = trace_edges(fleet, seed=seed)
+        if edges is not None:
+            det = RuntimeFailCloseDetector(edges=edges)
+            # gen_ingest_s counts the alias tables, as it always has
+            t0 = time.perf_counter()
+            edges.sampling_tables()
+            tables.set(edges=edges.n)
     is_arrays = isinstance(fleet, FleetState)
     if edges is None:
         # edge-free fleet: same contract, empty evidence and a 0-unsafe
@@ -541,8 +557,6 @@ def runtime_analysis(fleet: Union[Dict[str, ServiceSpec], FleetState],
     if n_records is None:
         n_records = 400 * max(1, edges.n)
 
-    det = RuntimeFailCloseDetector(edges=edges)
-    t0 = time.perf_counter()
     pending = None            # overlap device sampling with host scatter-add
     for chunk in _iter_trace_chunks(edges, n_records, seed,
                                     AMBIENT_CALLEE_FAILURE,
@@ -555,17 +569,19 @@ def runtime_analysis(fleet: Union[Dict[str, ServiceSpec], FleetState],
         det.ingest_batch(*pending)
     gen_ingest_s = time.perf_counter() - t0
 
-    mask = det.detect_mask()
-    found = {edges.edge_names[i] for i in np.flatnonzero(mask)}
-    truth = edges.unsafe_keys()
-    cold = edges.cold_keys()
-    tp = found & truth
-    # the detections ARE the graph: certification/planning downstream run
-    # on what this layer found, not on the planted ground truth
-    if is_arrays:
-        graph = CallGraph.from_detection_mask(fleet, mask)
-    else:
-        graph = CallGraph.from_detections(fleet, found)
+    with obs.span("ufa.detect.mask"):
+        mask = det.detect_mask()
+    with obs.span("ufa.detect.verdicts"):
+        found = {edges.edge_names[i] for i in np.flatnonzero(mask)}
+        truth = edges.unsafe_keys()
+        cold = edges.cold_keys()
+        tp = found & truth
+        # the detections ARE the graph: certification/planning downstream
+        # run on what this layer found, not on the planted ground truth
+        if is_arrays:
+            graph = CallGraph.from_detection_mask(fleet, mask)
+        else:
+            graph = CallGraph.from_detections(fleet, found)
     return {
         "found": found,
         "graph": graph,

@@ -96,9 +96,11 @@ def _fused_verdicts(consts: Dict, p: Dict, ts, temporal: bool,
     (a traced f32 scalar, or None) threads the opt-in soft relaxation
     into both kernels — sigmoid verdict indicators for the capacity
     optimizer; None traces the historical bit-exact ops."""
-    out = dict(scenario_outcome(consts["a"], p, tau))
+    with jax.named_scope("ufa_analytic"):
+        out = dict(scenario_outcome(consts["a"], p, tau))
     if temporal:
-        tsum = timeline_verdicts(consts["t"], p, ts, tau)
+        with jax.named_scope("ufa_timeline"):
+            tsum = timeline_verdicts(consts["t"], p, ts, tau)
         out.update({f"t_{k}": v for k, v in tsum.items()})
     return out
 
@@ -113,9 +115,11 @@ def _fused_verdicts_block(consts: Dict, p: Dict, ts, temporal: bool,
     float32-tight availability integral.  Soft mode (``tau``) always
     takes the scan path: the Pallas reducer is hard-only."""
     if reducer == "pallas" and temporal and tau is None:
-        out = dict(jax.vmap(
-            lambda q: dict(scenario_outcome(consts["a"], q)))(p))
-        tsum = timeline_verdicts_batch(consts["t"], p, ts)
+        with jax.named_scope("ufa_analytic"):
+            out = dict(jax.vmap(
+                lambda q: dict(scenario_outcome(consts["a"], q)))(p))
+        with jax.named_scope("ufa_timeline"):
+            tsum = timeline_verdicts_batch(consts["t"], p, ts)
         out.update({f"t_{k}": v for k, v in tsum.items()})
         return out
     return jax.vmap(
@@ -173,16 +177,19 @@ def _run_chunks_dep(consts, dep, pchunks, invchunks, storm_invchunks,
 
     def body(consts, dep, pchunks, invchunks, storm_invchunks, dark_u, ts,
              tau):
-        counts, frac, n_dark = broken_critical_fractions(dark_u, dep)
+        with jax.named_scope("ufa_dependency"):
+            counts, frac, n_dark = broken_critical_fractions(dark_u, dep)
 
         def one(args):
             p, inv, sinv = args
-            p = dict(p, dep_broken_frac=frac[inv],
-                     storm_broken_frac=frac[sinv])
+            with jax.named_scope("ufa_dependency"):
+                p = dict(p, dep_broken_frac=frac[inv],
+                         storm_broken_frac=frac[sinv])
+                dep_out = {"dep_n_broken_critical": counts[inv],
+                           "dep_n_dark": n_dark[inv]}
             out = _fused_verdicts_block(consts, p, ts, temporal, reducer,
                                         tau)
-            out["dep_n_broken_critical"] = counts[inv]
-            out["dep_n_dark"] = n_dark[inv]
+            out.update(dep_out)
             return out
         return lax.map(one, (pchunks, invchunks, storm_invchunks))
     specs = (P(), P(), _WIDE, _WIDE, _WIDE, P(), P(), P())
@@ -396,23 +403,29 @@ class SweepEngine:
         [0, 1] (float, not bool).  Forces the scan reducer (the Pallas
         verdict reduction is hard-only); ``None`` runs the historical
         bit-exact program."""
-        grid = scenario_grid() if grid is None else grid
-        n = validate_grid(grid)
-        tau = (None if soft_tau is None
-               else jnp.asarray(soft_tau, jnp.float32))
-        shape = bucket_shape(n, self.chunk)
         # one enabled() branch per run() call — free off (and the result
         # below is host-materialized, so the interior timing is honest)
         meter = obs.enabled()
         if meter:
             t0 = time.perf_counter()
             variants0 = compiled_variants()
-        fn, args, kw = self._pipeline(grid, dep_broken_frac, temporal, tau)
-        out = fn(*args, **kw)
-
-        result = {k: np.asarray(v).reshape(-1, *v.shape[2:])[:n]
-                  for k, v in out.items()}
-        result.update({k: np.asarray(v) for k, v in grid.items()})
+        with obs.span("ufa.sweep.run") as run_span:
+            with obs.span("ufa.sweep.prepare"):
+                grid = scenario_grid() if grid is None else grid
+                n = validate_grid(grid)
+                tau = (None if soft_tau is None
+                       else jnp.asarray(soft_tau, jnp.float32))
+                shape = bucket_shape(n, self.chunk)
+                fn, args, kw = self._pipeline(grid, dep_broken_frac,
+                                              temporal, tau)
+            run_span.set(scenarios=n, padded=shape[0] * shape[1],
+                         chunks=shape[0], sharded=int(kw["mesh"] is not None))
+            with obs.span("ufa.sweep.dispatch"):
+                out = fn(*args, **kw)
+            with obs.span("ufa.sweep.fetch", columns=len(out)):
+                result = {k: np.asarray(v).reshape(-1, *v.shape[2:])[:n]
+                          for k, v in out.items()}
+                result.update({k: np.asarray(v) for k, v in grid.items()})
         if meter:
             dt = time.perf_counter() - t0
             variants = compiled_variants()

@@ -45,7 +45,6 @@ arithmetic cannot miss a boundary the float64 event loop hits exactly.
 from __future__ import annotations
 
 import dataclasses
-import time
 from typing import Dict, Optional, Tuple
 
 import numpy as np
@@ -53,7 +52,6 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
-from repro import obs
 from repro.core.fleet_state import (AM, AO, POOL_OVERCOMMIT, POOL_STATELESS,
                                     RL, TM)
 from repro.core.tiers import (QOS_EVICT_UTILIZATION, RTO_SECONDS,
@@ -868,8 +866,6 @@ def sweep_timeline(cfg: TimelineConfig,
             params[k] = jnp.full(n, defaults[k], jnp.float32)
     ts = default_ts() if ts is None else np.asarray(ts, np.float64)
     tsj = jnp.asarray(ts, jnp.float32)
-    meter = obs.enabled()            # one branch per sweep — free off
-    t0 = time.perf_counter() if meter else 0.0
     if return_traces:
         traces, summary = _sweep_jit(cfg.as_consts(), params, tsj)
         out = {k: np.asarray(v) for k, v in summary.items()}
@@ -880,11 +876,6 @@ def sweep_timeline(cfg: TimelineConfig,
         # series) trace stack is never materialized
         summary = _sweep_summary_jit(cfg.as_consts(), params, tsj)
         out = {k: np.asarray(v) for k, v in summary.items()}
-    if meter:
-        dt = time.perf_counter() - t0
-        obs.inc("ufa_timeline_scenarios_total", n)
-        if dt > 0:
-            obs.set_gauge("ufa_timeline_scenarios_per_s", n / dt)
     return out
 
 

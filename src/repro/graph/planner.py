@@ -76,6 +76,12 @@ def plan_hardening(graph: CallGraph, batch: int = 64,
     transfers; nothing is re-traced and no (B, n) boolean matrix ever
     crosses the host boundary.
     """
+    with obs.span("ufa.planner.plan", batch=batch):
+        return _plan_hardening(graph, batch, max_rounds, service_weights)
+
+
+def _plan_hardening(graph: CallGraph, batch: int, max_rounds: int,
+                    service_weights) -> HardeningPlan:
     dark = np.asarray(graph.preemptible, bool)
     crit_live = graph.critical & ~dark
     closed = ~graph.fail_open.copy()           # host mirror of the mask
@@ -89,48 +95,53 @@ def plan_hardening(graph: CallGraph, batch: int = 64,
     rounds = 0
     certified = False
     while rounds < max_rounds:
-        broken_d, _ = fixed_point(dark_d, consts)
-        broken = np.asarray(broken_d[0])
-        n_bc = int(np.count_nonzero(broken & crit_live))
-        trajectory.append({"n_hardened": len(hardened),
-                           "n_broken_critical": n_bc})
-        obs.set_gauge("ufa_planner_broken_critical", n_bc)
-        if n_bc == 0:
-            certified = True
-            break
-        rounds += 1
-        obs.inc("ufa_planner_rounds_total")
-        # frontier: fail-close edges relaying breakage into a live caller
-        # (hardening an edge whose caller is itself dark changes nothing)
-        frontier = np.flatnonzero(closed & broken[graph.dst]
-                                  & ~dark[graph.src])
-        if len(frontier) == 0:
-            # a bare assert here vanished under ``python -O``, leaving the
-            # loop re-certifying the same stale state until max_rounds —
-            # fail loudly instead (mirrors EventLoop.max_events)
-            raise RuntimeError(
-                "plan_hardening stalled: "
-                f"{n_bc} broken critical service(s) after "
-                f"{len(hardened)} hardened edge(s) but no fail-close "
-                "frontier edge relays the breakage into a live caller — "
-                "the propagation verdicts and the edge mask disagree "
-                "(inconsistent graph state?); hardening cannot make "
-                "progress")
-        callers = np.unique(graph.src[frontier])
-        counts = radius_counts(callers, graph.n, consts, crit_d,
-                               weights=weights_d)
-        radius = np.zeros(graph.n, counts.dtype)
-        radius[callers] = counts
-        score = radius[graph.src[frontier]].astype(np.float64)
-        # tie-break on traffic volume (normalized to < 1 so it never
-        # outranks a whole extra critical service)
-        w = graph.weight[frontier].astype(np.float64)
-        score += w / (w.max() + 1.0)
-        pick = frontier[np.argsort(-score, kind="stable")[:batch]]
-        obs.inc("ufa_planner_hardened_edges_total", int(len(pick)))
-        hardened.extend(int(i) for i in pick)
-        closed[pick] = False
-        consts = harden_consts(consts, jnp.asarray(pick))
+        with obs.span("ufa.planner.round") as round_span:
+            broken_d, _ = fixed_point(dark_d, consts)
+            broken = np.asarray(broken_d[0])
+            n_bc = int(np.count_nonzero(broken & crit_live))
+            round_span.set(broken_critical=n_bc)
+            trajectory.append({"n_hardened": len(hardened),
+                               "n_broken_critical": n_bc})
+            obs.set_gauge("ufa_planner_broken_critical", n_bc)
+            if n_bc == 0:
+                certified = True
+                break
+            rounds += 1
+            obs.inc("ufa_planner_rounds_total")
+            # frontier: fail-close edges relaying breakage into a live
+            # caller (hardening an edge whose caller is itself dark
+            # changes nothing)
+            frontier = np.flatnonzero(closed & broken[graph.dst]
+                                      & ~dark[graph.src])
+            if len(frontier) == 0:
+                # a bare assert here vanished under ``python -O``, leaving
+                # the loop re-certifying the same stale state until
+                # max_rounds — fail loudly instead (mirrors
+                # EventLoop.max_events)
+                raise RuntimeError(
+                    "plan_hardening stalled: "
+                    f"{n_bc} broken critical service(s) after "
+                    f"{len(hardened)} hardened edge(s) but no fail-close "
+                    "frontier edge relays the breakage into a live caller "
+                    "— the propagation verdicts and the edge mask "
+                    "disagree (inconsistent graph state?); hardening "
+                    "cannot make progress")
+            callers = np.unique(graph.src[frontier])
+            counts = radius_counts(callers, graph.n, consts, crit_d,
+                                   weights=weights_d)
+            radius = np.zeros(graph.n, counts.dtype)
+            radius[callers] = counts
+            score = radius[graph.src[frontier]].astype(np.float64)
+            # tie-break on traffic volume (normalized to < 1 so it never
+            # outranks a whole extra critical service)
+            w = graph.weight[frontier].astype(np.float64)
+            score += w / (w.max() + 1.0)
+            pick = frontier[np.argsort(-score, kind="stable")[:batch]]
+            round_span.set(frontier=len(frontier), picked=len(pick))
+            obs.inc("ufa_planner_hardened_edges_total", int(len(pick)))
+            hardened.extend(int(i) for i in pick)
+            closed[pick] = False
+            consts = harden_consts(consts, jnp.asarray(pick))
     g = graph.harden(hardened)
     if not certified:
         # ran out of rounds after a harden — the last cert is stale

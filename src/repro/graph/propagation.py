@@ -42,6 +42,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
+from repro import obs
 from repro.graph.callgraph import CallGraph
 from repro.kernels import backend as _backend
 from repro.kernels.ufa import propagation as _pallas_prop
@@ -324,13 +325,14 @@ def certify(graph: CallGraph, dark: Optional[np.ndarray] = None
     if dark is None:
         dark = graph.preemptible
     dark = np.asarray(dark, bool)
-    broken_b, rounds = propagate_many(graph, dark[None, :])
-    broken = broken_b[0]
-    bc = broken & graph.critical & ~dark
-    # direct causes: criticals with a fail-close edge into the dark set
-    direct_edge = ~graph.fail_open & np.asarray(dark, bool)[graph.dst]
-    direct = np.zeros(graph.n, bool)
-    direct[graph.src[direct_edge]] = True
+    with obs.span("ufa.graph.certify", services=graph.n):
+        broken_b, rounds = propagate_many(graph, dark[None, :])
+        broken = broken_b[0]
+        bc = broken & graph.critical & ~dark
+        # direct causes: criticals with a fail-close edge into the dark set
+        direct_edge = ~graph.fail_open & np.asarray(dark, bool)[graph.dst]
+        direct = np.zeros(graph.n, bool)
+        direct[graph.src[direct_edge]] = True
     return Certification(
         ok=not bc.any(), broken=broken, broken_critical=bc,
         n_broken_critical=int(bc.sum()),
@@ -384,17 +386,18 @@ def blackhole_ensemble(graph: CallGraph, n_scenarios: int = 256,
                      if kind == "grid"
                      else rng.uniform(0.05, 1.0, n_scenarios))
     fractions = np.asarray(fractions, np.float64)
-    u = rng.random(graph.n)
-    dark = (u[None, :] < fractions[:, None]) & graph.preemptible[None, :]
-    broken, rounds = propagate_many(graph, dark)
-    bc = broken & graph.critical[None, :]
-    return {
-        "dark_fraction": fractions,
-        "n_dark": dark.sum(axis=1),
-        "n_broken": broken.sum(axis=1),
-        "n_broken_critical": bc.sum(axis=1),
-        "broken_critical_frac": bc.sum(axis=1)
-        / max(1, int(graph.critical.sum())),
-        "ok": ~bc.any(axis=1),
-        "rounds": np.int32(rounds),
-    }
+    with obs.span("ufa.graph.ensemble", scenarios=len(fractions)):
+        u = rng.random(graph.n)
+        dark = (u[None, :] < fractions[:, None]) & graph.preemptible[None, :]
+        broken, rounds = propagate_many(graph, dark)
+        bc = broken & graph.critical[None, :]
+        return {
+            "dark_fraction": fractions,
+            "n_dark": dark.sum(axis=1),
+            "n_broken": broken.sum(axis=1),
+            "n_broken_critical": bc.sum(axis=1),
+            "broken_critical_frac": bc.sum(axis=1)
+            / max(1, int(graph.critical.sum())),
+            "ok": ~bc.any(axis=1),
+            "rounds": np.int32(rounds),
+        }

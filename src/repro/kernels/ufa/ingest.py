@@ -111,6 +111,7 @@ def ingest_hist(edge_id: jnp.ndarray, callee_failed: jnp.ndarray,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
+        name="ingest_hist",
     )(key)
     return counts.reshape(-1)[:n_edges * N_CODES].reshape(n_edges, N_CODES)
 

@@ -155,6 +155,7 @@ def fixed_point_ell(dark: jnp.ndarray, ell_dst: jnp.ndarray,
         compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=_vmem_limit(n_pad, block_s)),
         interpret=interpret,
+        name="propagation_round",
     )
 
     def cond(state):

@@ -113,6 +113,7 @@ def timeline_reduce(avail: jnp.ndarray, util: jnp.ndarray,
                    jax.ShapeDtypeStruct((s_pad, R, 1), jnp.float32),
                    jax.ShapeDtypeStruct((s_pad, R, 1), jnp.int32)],
         interpret=interpret,
+        name="timeline_reduce",
     )(jnp.pad(avail, pad), jnp.pad(util, pad), jnp.pad(cloud, pad),
       frac, dt2, ts2)
     out = dict(zip(("avail_int", "avail_min", "util_peak", "cloud_peak"),

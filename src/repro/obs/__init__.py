@@ -16,6 +16,13 @@ bool when the plane is off::
     ...
     if obs.enabled():                       # one branch per call
         obs.inc("ufa_ingest_records_total", n, backend="numpy")
+
+and mark their stages with ``obs.span``, which lands on the
+``jax.profiler`` trace when one is being taken (and on an attached
+tracer's host track)::
+
+    with obs.span("ufa.sweep.fetch", columns=k):
+        ...
 """
 
 from __future__ import annotations
@@ -24,12 +31,13 @@ from typing import Dict, Optional, Tuple
 
 from .registry import (Counter, Gauge, Histogram, Metric, Registry,
                        default_registry, disable, enable, enabled)
-from .trace import Tracer, get_tracer, set_tracer, validate_chrome_trace
+from .trace import (Span, Tracer, get_tracer, set_tracer, span,
+                    validate_chrome_trace)
 
 __all__ = [
-    "Counter", "Gauge", "Histogram", "Metric", "Registry", "Tracer",
+    "Counter", "Gauge", "Histogram", "Metric", "Registry", "Span", "Tracer",
     "default_registry", "enable", "disable", "enabled",
-    "get_tracer", "set_tracer", "validate_chrome_trace",
+    "get_tracer", "set_tracer", "span", "validate_chrome_trace",
     "CATALOG", "inc", "set_gauge", "observe", "value", "describe",
 ]
 
@@ -75,12 +83,6 @@ CATALOG: Dict[str, Tuple[str, str, Tuple[str, ...],
     "ufa_sweep_compile_misses_total": (
         "counter", "jit cache misses (new compiled variants) observed "
         "across SweepEngine.run calls", (), None),
-    # -- temporal kernel (core/timeline_sim.py) -------------------------
-    "ufa_timeline_scenarios_total": (
-        "counter", "scenarios evaluated by sweep_timeline", (), None),
-    "ufa_timeline_scenarios_per_s": (
-        "gauge", "throughput of the most recent sweep_timeline call",
-        (), None),
     # -- hardening planner / regression gate (graph/planner.py) ---------
     "ufa_planner_rounds_total": (
         "counter", "hardening-planner greedy rounds", (), None),

@@ -1,20 +1,12 @@
 """Phase timing + JAX-aware pipeline profiling.
 
 A ``Profiler`` times named phases of a pipeline run (detect, plan,
-sweep, export, ...) into the ``ufa_phase_seconds`` histogram and — when
-a tracer is attached — onto the host track of the Chrome trace.  The
-JAX-aware pieces:
-
-  * ``phase(..., sync=tree)`` calls ``jax.block_until_ready`` on the
-    tree before stopping the clock, so async-dispatched device work is
-    charged to the phase that launched it instead of whoever touches
-    the result first;
-  * ``jit_cache_watch`` diffs a jit-cache size callable (e.g.
-    ``sweep_engine.compiled_variants``) around a block, turning
-    recompiles into a counter delta + gauge;
-  * ``throughput`` / ``padding_waste`` are the shared recording shims
-    the engine call sites use, so gauge/counter naming stays in one
-    place.
+sweep, export, ...) into the ``ufa_phase_seconds`` histogram.  Each phase
+is a host span (``repro.obs.trace.Span``): on the profiler's trace when
+one is being taken, and on the host track of the attached tracer.
+``phase(..., sync=tree)`` calls ``jax.block_until_ready`` on the tree
+before stopping the clock, so async-dispatched device work is charged to
+the phase that launched it instead of whoever touches the result first.
 
 ``jax`` is imported lazily (only when ``sync`` is actually used), so
 the module itself stays importable in jax-free contexts.
@@ -24,9 +16,10 @@ from __future__ import annotations
 
 import time
 from contextlib import contextmanager
-from typing import Any, Callable, Dict, Optional
+from typing import Any, Dict
 
 from repro import obs
+from repro.obs.trace import Span
 
 
 class Profiler:
@@ -41,52 +34,14 @@ class Profiler:
         """Time a named phase.  ``sync`` is an optional pytree to
         ``block_until_ready`` before the clock stops."""
         t0 = time.perf_counter()
-        span = (self.tracer.span(name, **args)
-                if self.tracer is not None else None)
-        if span is not None:
-            span.__enter__()
+        span = Span(name, args, self.tracer).__enter__()
         try:
             yield self
         finally:
             if sync is not None:
                 import jax
                 jax.block_until_ready(sync)
-            if span is not None:
-                span.__exit__(None, None, None)
+            span.__exit__(None, None, None)
             dt = time.perf_counter() - t0
             self.phases[name] = dt
             obs.observe("ufa_phase_seconds", dt, phase=name)
-
-    @contextmanager
-    def jit_cache_watch(self, cache_size: Callable[[], int],
-                        gauge: str = "ufa_sweep_compiled_variants",
-                        misses: str = "ufa_sweep_compile_misses_total"):
-        """Diff a jit-cache size around a block: new entries are compile
-        misses (counter), the post size a gauge."""
-        before = cache_size()
-        try:
-            yield
-        finally:
-            after = cache_size()
-            obs.set_gauge(gauge, after)
-            if after > before:
-                obs.inc(misses, after - before)
-
-
-# ---------------------------------------------------------------------------
-# recording shims shared by the engine call sites
-# ---------------------------------------------------------------------------
-
-def throughput(kind: str, n: int, seconds: float, **labels):
-    """Record one {ingest,sweep,timeline} call's throughput: the
-    ``*_total`` counter and the ``*_per_s`` gauge for ``kind``."""
-    obs.inc(f"ufa_{kind}_total", n, **labels)
-    if seconds > 0:
-        obs.set_gauge(f"ufa_{kind}_per_s", n / seconds)
-
-
-def padding_waste(n: int, padded: int,
-                  gauge: str = "ufa_sweep_padding_waste_ratio"):
-    """Record the padding-waste fraction of a bucket-padded mega-batch."""
-    if padded > 0:
-        obs.set_gauge(gauge, (padded - n) / padded)

@@ -17,19 +17,27 @@ Two clock domains share one trace, kept apart as separate *processes*
     (a 45 s MBB wave shows up 45 s wide).  Handler host wall-time is
     attached as an arg instead.
   * **host** (pid ``HOST_PID``) — wall-clock phases from
-    ``Profiler``/``Tracer.span()`` (ingest, compile, sweep, export).
+    ``Profiler``/``Tracer.span()`` and the program's ``span()`` sites
+    (ingest, compile, sweep, export).
+
+Every host span is also a ``jax.profiler.TraceAnnotation`` when ``jax`` is
+already imported: under an active ``jax.profiler`` trace it lands in the
+same ``.xplane.pb`` as the device's operations, on the same clock, with
+its counts as the event's stats.  So the device's idle gaps can be laid
+against the program's own stages.
 
 Timestamps are microseconds (the format's native unit); sim seconds
 map 1 s → 1 µs·1e6 so durations read naturally in Perfetto's ruler.
-Zero third-party deps — stdlib ``json`` and ``time`` only.
+Zero third-party deps — stdlib ``json``, ``sys`` and ``time`` only;
+``jax.profiler`` is used only where the process has imported ``jax``.
 """
 
 from __future__ import annotations
 
 import json
+import sys
 import threading
 import time
-from contextlib import contextmanager
 from typing import Any, Dict, List, Optional
 
 SIM_PID = 1       # simulation-time track
@@ -102,15 +110,10 @@ class Tracer:
     def _host_now_us(self) -> float:
         return (time.perf_counter() - self._t0_host) * _S_TO_US
 
-    @contextmanager
-    def span(self, name: str, **args):
-        """Wall-clock span on the host track (profiler phases)."""
-        t0 = self._host_now_us()
-        try:
-            yield self
-        finally:
-            self.complete(name, t0, self._host_now_us() - t0,
-                          pid=HOST_PID, args=args or None)
+    def span(self, name: str, **args) -> "Span":
+        """Wall-clock span on the host track (profiler phases), and on the
+        ``jax.profiler`` trace when one is being taken."""
+        return Span(name, args, self)
 
     # -- export ---------------------------------------------------------
     def to_chrome(self) -> Dict[str, Any]:
@@ -125,6 +128,60 @@ class Tracer:
 
     def __len__(self) -> int:
         return len(self._events)
+
+
+# ---------------------------------------------------------------------------
+# Host spans: on the profiler's clock, and on a tracer's host track
+# ---------------------------------------------------------------------------
+
+
+class Span:
+    """One host span: a ``jax.profiler.TraceAnnotation`` (where ``jax`` is
+    imported and a profiler trace is being taken) and, with a ``tracer``,
+    a complete event on its host track.  ``counts`` are host integers the
+    caller already holds; ``set`` adds those learned inside the span."""
+
+    __slots__ = ("name", "counts", "tracer", "_ann", "_t0")
+
+    def __init__(self, name: str, counts: Dict[str, Any],
+                 tracer: Optional[Tracer]):
+        self.name, self.counts, self.tracer = name, counts, tracer
+        self._ann = None
+
+    def __enter__(self) -> "Span":
+        prof = sys.modules.get("jax.profiler")
+        if prof is not None and prof.TraceAnnotation.is_enabled():
+            self._ann = prof.TraceAnnotation(self.name, **self.counts)
+            self._ann.__enter__()
+        if self.tracer is not None:
+            self._t0 = self.tracer._host_now_us()
+        return self
+
+    def set(self, **counts):
+        self.counts.update(counts)
+        if self._ann is not None:
+            self._ann.set_metadata(**counts)
+
+    def __exit__(self, *exc):
+        if self._ann is not None:
+            self._ann.__exit__(*exc)
+            self._ann = None
+        if self.tracer is not None:
+            self.tracer.complete(self.name, self._t0,
+                                 self.tracer._host_now_us() - self._t0,
+                                 pid=HOST_PID, args=self.counts or None)
+        return False
+
+
+def span(name: str, **counts) -> Span:
+    """A host span of the program named ``name`` (``ufa.<layer>.<stage>``),
+    recorded on the profiler's trace and on the attached tracer, if any::
+
+        with obs.span("ufa.sweep.run", scenarios=n) as sp:
+            ...
+            sp.set(chunks=k)
+    """
+    return Span(name, counts, _TRACER)
 
 
 # ---------------------------------------------------------------------------

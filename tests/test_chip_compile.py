@@ -127,8 +127,14 @@ def test_fused_sweep_chunk_compiles_for_v5e(one_chip, compiled_kernels,
                                            sweep_pipeline):
     fn, args, kw = sweep_pipeline
     compiled = fn.lower(*_shapes(args, one_chip), **kw).compile()
+    text = compiled.as_text()
     # the propagation kernel and the reducer kernel
-    assert compiled.as_text().count("tpu_custom_call") >= 2
+    assert text.count("tpu_custom_call") >= 2
+    # each stage's named scope survives into the compiled instructions'
+    # metadata, and each kernel is found by its name
+    for name in ("ufa_dependency/", "ufa_analytic/", "ufa_timeline/",
+                 "%propagation_round.", "%timeline_reduce."):
+        assert name in text, name
 
 
 def test_sharded_sweep_chunk_compiles_for_four_v5e(topo, compiled_kernels,
