@@ -26,8 +26,13 @@ three stages into ONE jitted, device-parallel pipeline:
     within a padding bucket; see ``bucket_shape`` / ``compiled_variants``);
   * the scenario width is split across devices (a ``NamedSharding``
     over a 1-D "scenarios" mesh, and ``shard_map`` so that each device
-    runs the whole pipeline on its own slice), and the scenario buffers
-    are donated to the pipeline.
+    runs the whole pipeline on its own slice);
+  * the program packs its result columns, per dtype, into one
+    ``(rows, n_chunks, width)`` buffer each (``_Packed``), so the host
+    fetches two or three arrays in one batched ``jax.device_get`` rather
+    than one blocking copy per column, and unpacks them into views.  No
+    packed output has the shape of a scenario buffer, so none of those is
+    donated: there is nothing it could be aliased to.
 
 Config (fleet aggregates, timeline constants, graph edges) is precomputed
 once into device-resident arrays and passed as *traced* arguments, so the
@@ -42,6 +47,7 @@ not.
 
 from __future__ import annotations
 
+import math
 import time
 from functools import partial
 from typing import Dict, Optional
@@ -127,38 +133,91 @@ def _fused_verdicts_block(consts: Dict, p: Dict, ts, temporal: bool,
 
 
 _WIDE = P(None, "scenarios")          # (n_chunks, width): split the width
+_PACKED = P(None, None, "scenarios")  # (rows, n_chunks, width): split too
+
+
+@jax.tree_util.register_pytree_node_class
+class _Packed:
+    """The pipeline's result columns, one buffer per dtype shaped
+    ``(rows, n_chunks, width)``: the scenario axis is minor, so each
+    column is one contiguous host row.  ``layout`` is the pytree's static
+    aux data, ``(key, buffer, first row, trailing shape)`` per column:
+    the host unpacks any compiled variant without tracing it again."""
+
+    def __init__(self, buffers, layout):
+        self.buffers, self.layout = tuple(buffers), layout
+
+    def tree_flatten(self):
+        return self.buffers, self.layout
+
+    @classmethod
+    def tree_unflatten(cls, layout, buffers):
+        return cls(buffers, layout)
+
+
+def _pack(out: Dict) -> _Packed:
+    """Stack ``lax.map``'s ``(n_chunks, width, *trailing)`` outputs by
+    dtype into ``_Packed`` buffers, one row per trailing index (a minor
+    axis of 7 would pad to 128 lanes on the TPU).  Leaves are grouped by
+    their traced dtype, so no dtype is promoted."""
+    groups: Dict = {}
+    for k, v in out.items():
+        rows = jnp.moveaxis(v, tuple(range(2, v.ndim)),
+                            tuple(range(v.ndim - 2)))
+        groups.setdefault(v.dtype, []).append(
+            (k, rows.reshape(-1, *v.shape[:2]), v.shape[2:]))
+    buffers, layout = [], []
+    for i, cols in enumerate(groups.values()):
+        row = 0
+        for k, rows, trailing in cols:
+            layout.append((k, i, row, trailing))
+            row += rows.shape[0]
+        buffers.append(jnp.concatenate([rows for _, rows, _ in cols]))
+    return _Packed(buffers, tuple(layout))
+
+
+def _unpack(packed: _Packed, n: int) -> Dict[str, np.ndarray]:
+    """Fetch the packed buffers in one ``jax.device_get`` (every copy is
+    issued before the host waits on any) and cut them into the first
+    ``n`` scenarios of each column: views, ``(n, *trailing)``."""
+    host = jax.device_get(packed.buffers)
+    out = {}
+    for k, i, row, trailing in packed.layout:
+        size = math.prod(trailing)
+        cols = host[i][row:row + size].reshape(size, -1)[:, :n]
+        out[k] = cols.T.reshape(n, *trailing)
+    return out
 
 
 def _per_device(fn, mesh, in_specs):
     """Run ``fn`` once per device of the 1-D scenario ``mesh`` on that
     device's slice of the scenario width (``shard_map``; ``in_specs``
-    marks the ``(n_chunks, width)`` arguments ``_WIDE``, the rest ``P()``).
-    Every verdict is per scenario, so the body needs no collectives, and
-    each device runs the Pallas reducer on its own slice.  ``mesh=None``
-    runs ``fn`` as it is."""
+    marks the ``(n_chunks, width)`` arguments ``_WIDE``, the rest ``P()``;
+    each device packs its own slice of the ``_Packed`` result).  Every
+    verdict is per scenario, so the body needs no collectives, and each
+    device runs the Pallas reducer on its own slice.  ``mesh=None`` runs
+    ``fn`` as it is."""
     if mesh is None:
         return fn
-    return shard_map(fn, mesh=mesh, in_specs=in_specs, out_specs=_WIDE)
+    return shard_map(fn, mesh=mesh, in_specs=in_specs, out_specs=_PACKED)
 
 
-@partial(jax.jit, static_argnames=("temporal", "reducer", "mesh"),
-         donate_argnums=(1,))
+@partial(jax.jit, static_argnames=("temporal", "reducer", "mesh"))
 def _run_chunks(consts, pchunks, ts, tau=None, *, temporal,
                 reducer="scan", mesh=None):
     """Fused pipeline, explicit ``dep_broken_frac``: lax.map over
     ``(n_chunks, width)`` scenario mega-batches of the fused scenario
     block function.  ``tau=None`` vs a traced scalar hit different jit
     cache entries (different pytree structures), so the hard path's
-    compiled program is untouched by soft runs."""
+    compiled program is untouched by soft runs.  Returns ``_Packed``."""
     def body(consts, pchunks, ts, tau):
-        return lax.map(lambda p: _fused_verdicts_block(
-            consts, p, ts, temporal, reducer, tau), pchunks)
+        return _pack(lax.map(lambda p: _fused_verdicts_block(
+            consts, p, ts, temporal, reducer, tau), pchunks))
     return _per_device(body, mesh, (P(), _WIDE, P(), P()))(
         consts, pchunks, ts, tau)
 
 
-@partial(jax.jit, static_argnames=("temporal", "reducer", "mesh"),
-         donate_argnums=(2, 3, 4))
+@partial(jax.jit, static_argnames=("temporal", "reducer", "mesh"))
 def _run_chunks_dep(consts, dep, pchunks, invchunks, storm_invchunks,
                     dark_u, ts, tau=None, *, temporal, reducer="scan",
                     mesh=None):
@@ -172,7 +231,7 @@ def _run_chunks_dep(consts, dep, pchunks, invchunks, storm_invchunks,
     while_loop settles both stages, and each scenario gathers its storm
     verdict (``storm_broken_frac``) by its second index.  Sharded, every
     device settles the (few) unique dark sets itself and gathers for its
-    own scenarios."""
+    own scenarios.  Returns ``_Packed``."""
     from repro.graph.propagation import broken_critical_fractions
 
     def body(consts, dep, pchunks, invchunks, storm_invchunks, dark_u, ts,
@@ -191,7 +250,7 @@ def _run_chunks_dep(consts, dep, pchunks, invchunks, storm_invchunks,
                                         tau)
             out.update(dep_out)
             return out
-        return lax.map(one, (pchunks, invchunks, storm_invchunks))
+        return _pack(lax.map(one, (pchunks, invchunks, storm_invchunks)))
     specs = (P(), P(), _WIDE, _WIDE, _WIDE, P(), P(), P())
     return _per_device(body, mesh, specs)(consts, dep, pchunks, invchunks,
                                           storm_invchunks, dark_u, ts, tau)
@@ -422,9 +481,9 @@ class SweepEngine:
                          chunks=shape[0], sharded=int(kw["mesh"] is not None))
             with obs.span("ufa.sweep.dispatch"):
                 out = fn(*args, **kw)
-            with obs.span("ufa.sweep.fetch", columns=len(out)):
-                result = {k: np.asarray(v).reshape(-1, *v.shape[2:])[:n]
-                          for k, v in out.items()}
+            with obs.span("ufa.sweep.fetch", columns=len(out.layout),
+                          transfers=len(out.buffers)):
+                result = _unpack(out, n)
                 result.update({k: np.asarray(v) for k, v in grid.items()})
         if meter:
             dt = time.perf_counter() - t0

@@ -114,7 +114,9 @@ def test_sweep_spans_nest_with_counts(traced):
         _inside(child, run)
     # in program order, one after the other
     assert stages[0][3] <= stages[1][2] and stages[1][3] <= stages[2][2]
-    assert stages[2][4]["columns"] == len(out) - len(_grid())
+    assert stages[2][4]["columns"] == len(out) - len(_grid()) == 39
+    # the result comes back packed, one device array per dtype
+    assert stages[2][4]["transfers"] <= 3
 
 
 def test_detect_spans_nest_with_counts(traced):

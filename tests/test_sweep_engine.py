@@ -9,6 +9,7 @@ import sys
 import textwrap
 
 import numpy as np
+import jax
 import jax.numpy as jnp
 import pytest
 
@@ -19,6 +20,7 @@ from repro.core.scenarios import (FleetAggregates, analytic_consts,
                                   sweep_scenarios,
                                   sweep_with_dependency_ensemble,
                                   _sweep_jit)
+from repro.core import sweep_engine
 from repro.core.service import synthesize_fleet
 from repro.core.sweep_engine import (CHUNK, MIN_BUCKET, SweepEngine,
                                      bucket_shape, compiled_variants,
@@ -199,6 +201,52 @@ def test_output_dtypes_pinned(parts):
         assert v.dtype in _ALLOWED, (k, v.dtype)
 
 
+# run() options per case; "trim" sweeps 600 rows in 256-wide chunks, so
+# the padded tail of the last chunk is cut off
+_FETCH_CASES = {
+    "dependency": {},
+    "explicit_dep_frac": {"dep_broken_frac": np.linspace(0.0, 0.3, 512)},
+    "temporal_off": {"temporal": False},
+    "soft_tau": {"soft_tau": 0.5},
+    "trim": {},
+}
+
+
+@pytest.mark.parametrize("case", list(_FETCH_CASES))
+def test_packed_fetch_matches_program_leaves(parts, monkeypatch, case):
+    """``run`` fetches the result packed per dtype; it must hand back what
+    ``np.asarray`` of each leaf of the program's own output gives (the
+    same pipeline jitted afresh with the packing left out): the same
+    keys, dtypes and shapes, bit-identical values."""
+    agg, cfg, graph = parts
+    kw = _FETCH_CASES[case]
+    grid = scenario_grid(evict_fraction=(1.0, 0.5))
+    if case == "trim":
+        grid = tile_grid(grid, 600)
+    eng = SweepEngine(agg, cfg, graph=graph, ts=default_ts(7200.0, 48),
+                      chunk=256)
+    got = eng.run(grid, **kw)
+
+    tau = (jnp.asarray(kw["soft_tau"], jnp.float32) if "soft_tau" in kw
+           else None)
+    fn, args, fkw = eng._pipeline(grid, kw.get("dep_broken_frac"),
+                                  kw.get("temporal", True), tau)
+    monkeypatch.setattr(sweep_engine, "_pack", lambda out: out)
+
+    def unpacked(*a, **k):          # a new function: a trace of its own
+        return fn.__wrapped__(*a, **k)
+    leaves = jax.jit(unpacked, static_argnames=tuple(fkw))(*args, **fkw)
+    n = len(next(iter(grid.values())))
+    want = {k: np.asarray(v).reshape(-1, *v.shape[2:])[:n]
+            for k, v in leaves.items()}
+    assert set(got) == set(want) | set(grid)
+    if kw.get("temporal", True):
+        assert got["t_time_to_restore_s"].shape == (n, 7)
+    for k, v in want.items():
+        assert (got[k].dtype, got[k].shape) == (v.dtype, v.shape), k
+        assert np.array_equal(got[k], v, equal_nan=True), k
+
+
 def _run(code, n_devices=1, x64=False):
     env = dict(os.environ)
     env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={n_devices}"
@@ -243,6 +291,7 @@ def test_sharded_equals_single_device():
         a, b = sharded.run(grid), single.run(grid)
         assert set(a) == set(b)
         for k in a:
+            assert (a[k].dtype, a[k].shape) == (b[k].dtype, b[k].shape), k
             assert np.array_equal(a[k], b[k], equal_nan=True), k
         print("OK", len(a["sla_ok"]))
     """)
