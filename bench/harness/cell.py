@@ -69,6 +69,7 @@ def run(cell: spec_mod.Cell, seed: int, seconds: float, trace: bool,
     counter = CompileCounter()
     job = jobs.make(cell.config, cell.traffic, cell.chips, seed)
     job.setup()
+    print(f"[bench] fleet {job.describe()}", file=log, flush=True)
     job.warm()
     # what set-up built lives on: keep the collector from rescanning it
     gc.collect()

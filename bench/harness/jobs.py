@@ -36,6 +36,18 @@ class Job:
         self.chips, self.seed = chips, int(seed)
         self.limits = traffic["check"]["limits"]
 
+    def _build(self):
+        """The configuration's fleet; its columns go to ``self.cols``."""
+        fs = fleet_mod.build(self.config)
+        self.cols = fleet_mod.columns(fs)
+        return fs
+
+    def describe(self) -> str:
+        c = self.cols
+        return (f"{self.config['name']}: {len(c['tier'])} services, "
+                f"{len(c['src'])} edges, "
+                f"{int(np.count_nonzero(~c['fail_open']))} fail-close")
+
     def release(self):
         """Drop the program's state before the references run."""
 
@@ -57,8 +69,7 @@ class SweepJob(Job):
         from repro.graph import CallGraph
 
         t = self.traffic
-        fs = fleet_mod.build(self.config)
-        self.cols = fleet_mod.columns(fs)
+        fs = self._build()
         orch = Orchestrator(fs, RegionCapacity.for_fleet(
             self.config["name"], fs), scale=1.0)
         self.ts = (np.arange(t["steps"], dtype=np.float64)
@@ -145,8 +156,7 @@ class DetectJob(Job):
     unit = "records"
 
     def setup(self):
-        self.fs = fleet_mod.build(self.config)
-        self.cols = fleet_mod.columns(self.fs)
+        self.fs = self._build()
         t = self.traffic
         self.n_records = int(t["records_per_edge"]) * len(self.cols["src"])
         self.chunk = int(t["chunk_records"])
@@ -184,7 +194,12 @@ class DetectJob(Job):
 
         c = self.cols
         unsafe = ~c["fail_open"]
-        weight = ref.edge_weights(c["tier"], c["src"], c["dst"])
+        # Table 2 traffic is worked out here on its own; a deployment that
+        # states its own is sampled with the fleet's weights
+        if self.config.get("edge_weights", "table2") == "table2":
+            weight = ref.edge_weights(c["tier"], c["src"], c["dst"])
+        else:
+            weight = c["weight"]
         prob, alias, _ = ref.sampling_tables(weight, unsafe, seed)
         n_chunks = max(1, -(-self.n_records // self.chunk))
         keys = jax.random.split(jax.random.key(seed, impl="rbg"), n_chunks)
@@ -235,8 +250,7 @@ class HardenJob(Job):
 
     def setup(self):
         from repro.graph import CallGraph
-        fs = fleet_mod.build(self.config)
-        self.cols = fleet_mod.columns(fs)
+        fs = self._build()
         self.graph = CallGraph.from_fleet_state(fs)
         self.kept = []
 

@@ -2,7 +2,9 @@
 
 ``BENCHMARK.json`` names them; each lives in a file of its own:
 
-  configuration  the ``file`` its entry gives (under ``bench/configs/``)
+  configuration  the ``file`` its entry gives (under ``bench/configs/``),
+                 which may name its fleet builder and its edge weights
+                 (``harness/fleet.py``)
   traffic mix    ``bench/traffic/<traffic>.json``
   metric         ``bench/metrics/<metric name>.py``, whose ``read(ctx)``
                  returns the number, or None where it finds nothing to read

@@ -37,7 +37,8 @@ def test_metric_routing(bench):
     assert {m["name"] for m in det.end_to_end} == {"detect_records_per_s",
                                                   "setup_s"}
     assert {m["name"] for m in det.per_layer} == {
-        "device_idle.detect", "sample_ms.detect", "ingest_ms.detect"}
+        "device_idle.detect", "sample_ms.detect", "ingest_ms.detect",
+        "tables_ms.detect", "verdicts_ms.detect"}
 
 
 def test_every_config_file_and_metric_is_used(bench):
