@@ -114,23 +114,42 @@ def fixed_point(dark: jnp.ndarray, consts: Dict[str, jnp.ndarray]):
     trace-time static)."""
     if "ell_dst" in consts and consts["ell_dst"].shape[1] > 0:
         return _pallas_prop.fixed_point_ell(dark, consts["ell_dst"],
-                                            consts["ell_closed"])
+                                            consts["ell_closed"],
+                                            consts.get("ell_tag"))
     return _fixed_point(dark, consts["src"], consts["dst"],
                         consts["closed"])
 
 
 def _ell_topology(graph: CallGraph):
-    """Cached node-topology half of the ELL build (``ell_dst``/``slot``
-    depend only on src/dst/indptr, not on the fail-close mask, so they
+    """Cached node-topology half of the ELL build: ``(ell_dst, row, slot,
+    tag)`` of ``ell_split_from_csr``.  They depend only on
+    src/dst/indptr, not on the fail-close mask, so they
     survive ``harden``-style mask churn; the mask half is a cheap scatter
-    recomputed per ``edge_consts`` call)."""
+    recomputed per ``edge_consts`` call.  The base width follows the
+    degree distribution (``ell_width``): the largest degree, rounded up,
+    on a graph of like degrees."""
     cache = getattr(graph, "_ell_topology", None)
     if cache is None:
-        ell_dst, _, slot = _pallas_prop.ell_from_csr(
-            graph.n, graph.indptr, graph.dst, ~graph.fail_open)
-        cache = (ell_dst, slot)
+        width = _pallas_prop.ell_width(np.diff(graph.indptr))
+        ell_dst, _, row, slot, tag = _pallas_prop.ell_split_from_csr(
+            graph.n, graph.indptr, graph.dst, ~graph.fail_open, width)
+        cache = (ell_dst, row, slot, tag)
         object.__setattr__(graph, "_ell_topology", cache)
     return cache
+
+
+def layout_counts(graph: CallGraph) -> Dict[str, int]:
+    """What one propagation round walks on the path ``edge_consts``
+    takes: ``edges``; ``slots``, the slot loads of a round (every edge on
+    the XLA edge-list path; on the ELL path every slot of every row, pad
+    slots and a split row's load of its aux rows included);
+    ``split_rows``, the caller rows spilled into aux rows."""
+    out = {"edges": graph.n_edges, "slots": graph.n_edges, "split_rows": 0}
+    if _backend.use_ufa_kernels():
+        ell_dst, _, _, tag = _ell_topology(graph)
+        split = int(np.count_nonzero(tag[:graph.n] >= 0))
+        out.update(slots=ell_dst.size + split, split_rows=split)
+    return out
 
 
 def edge_consts(graph: CallGraph) -> Dict[str, jnp.ndarray]:
@@ -138,32 +157,39 @@ def edge_consts(graph: CallGraph) -> Dict[str, jnp.ndarray]:
     the fail-close mask, and — when the Pallas path is on
     (``backend.use_ufa_kernels()``) — the ELL adjacency the kernel
     consumes (``ell_dst``/``ell_closed`` (n, K), plus ``ell_slot`` (E,)
-    so ``harden_consts`` can flip individual edges in place)."""
+    so ``harden_consts`` can flip individual edges in place).  A split
+    layout adds its aux rows to ``ell_dst``/``ell_closed``, their tags
+    (``ell_tag``) and each edge's row (``ell_row`` (E,))."""
     out = {"src": jnp.asarray(graph.src, jnp.int32),
            "dst": jnp.asarray(graph.dst, jnp.int32),
            "closed": jnp.asarray(~graph.fail_open)}
     if _backend.use_ufa_kernels():
-        ell_dst, slot = _ell_topology(graph)
+        ell_dst, row, slot, tag = _ell_topology(graph)
         if ell_dst.shape[1] > 0:
             closed = ~graph.fail_open
             ell_closed = np.zeros(ell_dst.shape, bool)
-            ell_closed[graph.src, slot] = closed
+            ell_closed[row, slot] = closed
             out["ell_dst"] = jnp.asarray(ell_dst)
             out["ell_closed"] = jnp.asarray(ell_closed)
             out["ell_slot"] = jnp.asarray(slot)
+            if ell_dst.shape[0] > graph.n:
+                out["ell_row"] = jnp.asarray(row)
+                out["ell_tag"] = jnp.asarray(tag)
     return out
 
 
 def harden_consts(consts: Dict[str, jnp.ndarray],
                   pick: jnp.ndarray) -> Dict[str, jnp.ndarray]:
     """Convert edges ``pick`` (CSR indices) to fail-open in the device
-    consts — both the edge-list mask and, when present, its ELL mirror —
+    consts — both the edge-list mask and, when present, its ELL mirror,
+    at each edge's own row (an aux row where its caller's row is split) —
     without re-uploading anything else (the planner's per-round update).
     """
     out = dict(consts, closed=consts["closed"].at[pick].set(False))
     if "ell_closed" in consts:
+        rows = consts.get("ell_row", consts["src"])
         out["ell_closed"] = consts["ell_closed"].at[
-            consts["src"][pick], consts["ell_slot"][pick]].set(False)
+            rows[pick], consts["ell_slot"][pick]].set(False)
     return out
 
 
@@ -325,8 +351,10 @@ def certify(graph: CallGraph, dark: Optional[np.ndarray] = None
     if dark is None:
         dark = graph.preemptible
     dark = np.asarray(dark, bool)
-    with obs.span("ufa.graph.certify", services=graph.n):
+    with obs.span("ufa.graph.certify", services=graph.n,
+                  **layout_counts(graph)) as sp:
         broken_b, rounds = propagate_many(graph, dark[None, :])
+        sp.set(rounds=rounds)
         broken = broken_b[0]
         bc = broken & graph.critical & ~dark
         # direct causes: criticals with a fail-close edge into the dark set
@@ -386,10 +414,12 @@ def blackhole_ensemble(graph: CallGraph, n_scenarios: int = 256,
                      if kind == "grid"
                      else rng.uniform(0.05, 1.0, n_scenarios))
     fractions = np.asarray(fractions, np.float64)
-    with obs.span("ufa.graph.ensemble", scenarios=len(fractions)):
+    with obs.span("ufa.graph.ensemble", scenarios=len(fractions),
+                  **layout_counts(graph)) as sp:
         u = rng.random(graph.n)
         dark = (u[None, :] < fractions[:, None]) & graph.preemptible[None, :]
         broken, rounds = propagate_many(graph, dark)
+        sp.set(rounds=rounds)
         bc = broken & graph.critical[None, :]
         return {
             "dark_fraction": fractions,

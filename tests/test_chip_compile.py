@@ -28,6 +28,8 @@ from repro.kernels.ufa.propagation import fixed_point_ell
 from repro.kernels.ufa.reduce import timeline_reduce
 
 N_SERVICES, N_EDGES, ELL_K = 21_979, 120_827, 16
+# the trace-shaped fleet's split layout: base width 8, 9,550 aux rows
+SPLIT_K, SPLIT_AUX = 8, 9_550
 CHUNK_RECORDS = 4_000_000
 N_SCENARIOS, N_STEPS = 4096, 240
 
@@ -108,6 +110,12 @@ def _kernel_call(kernel):
         return (lambda d, e, c: fixed_point_ell(d, e, c, interpret=False),
                 [((256, N_SERVICES), bool), ((N_SERVICES, ELL_K), jnp.int32),
                  ((N_SERVICES, ELL_K), bool)])
+    if kernel == "propagation-split":
+        rows = N_SERVICES + SPLIT_AUX
+        return (lambda d, e, c, t: fixed_point_ell(d, e, c, t,
+                                                   interpret=False),
+                [((256, N_SERVICES), bool), ((rows, SPLIT_K), jnp.int32),
+                 ((rows, SPLIT_K), bool), ((rows,), jnp.int32)])
     f32 = jnp.float32
     return (lambda a, u, c, fr, ts: timeline_reduce(
         a, u, c, fr, ts, thresh=RESTORE_THRESH, interpret=False),
@@ -115,7 +123,8 @@ def _kernel_call(kernel):
         + [((N_SCENARIOS, N_STEPS, N_TIERS), f32), ((N_STEPS,), f32)])
 
 
-@pytest.mark.parametrize("kernel", ["ingest", "propagation", "reduce"])
+@pytest.mark.parametrize("kernel", ["ingest", "propagation",
+                                    "propagation-split", "reduce"])
 def test_kernel_compiles_for_v5e(one_chip, kernel):
     fn, shapes = _kernel_call(kernel)
     args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip) for s, d in shapes]

@@ -153,6 +153,34 @@ def test_planner_spans_nest_with_counts(traced):
     assert "picked" not in rounds[-1][4]
 
 
+def test_graph_spans_count_the_layout(tmp_path, monkeypatch):
+    """Certification and the ensemble carry what a propagation round walks
+    (edges, slot loads, split rows) and the rounds of their fixed point, on
+    a trace-shaped fleet whose hubs split rows of the kernel's layout."""
+    from repro.core.fleet_shapes import alibaba_fleet_state
+    from repro.graph import blackhole_ensemble, certify
+    from repro.graph.propagation import layout_counts
+    monkeypatch.setenv("REPRO_UFA_KERNELS", "1")
+    graph = CallGraph.from_fleet_state(alibaba_fleet_state(scale=0.05,
+                                                           seed=7))
+    certify(graph)                      # compile outside the trace
+    blackhole_ensemble(graph, n_scenarios=16, seed=1)
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        cert = certify(graph)
+        ens = blackhole_ensemble(graph, n_scenarios=16, seed=1)
+    finally:
+        jax.profiler.stop_trace()
+    spans = _spans(str(tmp_path))
+    counts = layout_counts(graph)
+    assert counts["edges"] == graph.n_edges and counts["split_rows"] > 0
+    assert counts["slots"] > counts["edges"]
+    assert _one(spans, "ufa.graph.certify")[4] == dict(
+        counts, services=graph.n, rounds=cert.rounds)
+    assert _one(spans, "ufa.graph.ensemble")[4] == dict(
+        counts, scenarios=16, rounds=int(ens["rounds"]))
+
+
 def test_sweep_output_identical_under_trace(traced, engine):
     _, traced_out, _ = traced
     plain = engine.run(_grid())

@@ -25,10 +25,13 @@ from repro.core.timeline_sim import config_for_fleet, default_ts
 from repro.graph import (CallGraph, blackhole_ensemble, blast_radius,
                          certify, plan_hardening, propagate, propagate_many)
 from repro.graph.callgraph import _build_csr
+from repro.graph.propagation import (edge_consts, fixed_point, harden_consts,
+                                     layout_counts)
 from repro.kernels.backend import default_interpret, use_ufa_kernels
 from repro.kernels.ufa.ingest import (N_CODES, ingest_hist, ref_ingest_hist)
-from repro.kernels.ufa.propagation import (ell_from_csr, fixed_point_ell,
-                                           ref_fixed_point)
+from repro.kernels.ufa.propagation import (ell_from_csr, ell_slots,
+                                           ell_split_from_csr, ell_width,
+                                           fixed_point_ell, ref_fixed_point)
 from repro.kernels.ufa.reduce import ref_timeline_reduce, timeline_reduce
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -278,6 +281,185 @@ def test_planner_backends_agree(monkeypatch, fleet_graph):
     assert plan_cpu.certified and plan_dev.certified
     assert plan_cpu.hardened_edges == plan_dev.hardened_edges
     assert plan_cpu.trajectory == plan_dev.trajectory
+
+
+# ---------------------------------------------------------------------------
+# kernel 1, split layout: graphs whose out-degrees are not all alike
+# ---------------------------------------------------------------------------
+
+
+def hub_graph(seed, n=120, hubs=(70, 45), hops=4):
+    """A seeded call graph with hubs far wider than the base width beside
+    callers of a few callees, and a fail-close relay chain ``hops`` deep
+    whose first link is the widest hub's last (spilled) callee: darkening
+    the chain's end breaks it one hop a round, through a split row."""
+    rng = np.random.default_rng(seed)
+    deg = rng.poisson(2.0, n)
+    deg[:len(hubs)] = hubs
+    src, dst = [], []
+    for u in range(n):
+        callees = rng.choice(np.delete(np.arange(n), u), deg[u],
+                             replace=False)
+        src += [u] * len(callees)
+        dst += callees.tolist()
+    src, dst = np.asarray(src), np.asarray(dst)
+    closed = rng.random(len(src)) < 0.3
+    chain = list(range(n - hops, n))             # chain[0] <- ... <- end
+    # no shortcut: the chain's links are its only fail-close edges
+    closed[np.isin(src, [0] + chain) & np.isin(dst, chain)] = False
+    for a, b in zip([0] + chain[:-1], chain):
+        keep = ~((src == a) & (dst == b))
+        src, dst, closed = src[keep], dst[keep], closed[keep]
+        src = np.append(src, a)
+        dst = np.append(dst, b)
+        closed = np.append(closed, True)
+    critical = np.zeros(n, bool)
+    critical[[0] + chain[:-1]] = True
+    g = _build_csr(n, src.astype(np.int32), dst.astype(np.int32), ~closed,
+                   np.ones(len(src), np.float32), critical, ~critical,
+                   [f"s{i}" for i in range(n)])
+    return g, chain[-1]
+
+
+def test_ell_width_follows_the_degrees():
+    """Like degrees keep the largest degree, rounded up (the paper fleet's
+    13 gives 16); a hub past 32 gives the width of fewest slots."""
+    assert ell_width(np.array([0, 3, 13, 6])) == 16
+    assert ell_width(np.array([32, 1])) == 32
+    deg = np.r_[np.full(500, 3), 600, 300]
+    k = ell_width(deg)
+    assert k % 8 == 0 and k <= 32
+    assert ell_slots(deg, k) == min(ell_slots(deg, w)
+                                    for w in range(8, 601, 8))
+    assert ell_slots(deg, k) < ell_slots(deg, 600) / 10
+
+
+def test_split_layout_places_every_edge():
+    g, _ = hub_graph(0)
+    K = ell_width(np.diff(g.indptr))
+    ell_dst, ell_closed, row, slot, tag = ell_split_from_csr(
+        g.n, g.indptr, g.dst, ~g.fail_open, K)
+    assert K == 8 and ell_dst.shape[0] > g.n
+    assert (ell_dst[row, slot] == g.dst).all()
+    assert (ell_closed[row, slot] == ~g.fail_open).all()
+    # each position holds one edge; pad slots are fail-open
+    assert len(np.unique(row.astype(np.int64) * K + slot)) == g.n_edges
+    filled = np.zeros(ell_dst.shape, bool)
+    filled[row, slot] = True
+    assert not ell_closed[~filled].any()
+    # a split caller names its last aux row; its aux rows chain in order
+    for u in np.flatnonzero(tag[:g.n] >= 0):
+        mine = np.unique(row[g.out_edges(u)])
+        aux = mine[mine >= g.n] - g.n
+        assert tag[g.n + aux].tolist() == [0] + [1] * (len(aux) - 1)
+        assert tag[u] == aux[-1] and (np.diff(aux) == 1).all()
+    deg = np.diff(g.indptr)
+    assert ell_dst.size + np.count_nonzero(tag[:g.n] >= 0) == ell_slots(deg,
+                                                                        K)
+
+
+@pytest.mark.parametrize("kernels", ["0", "1"], ids=["xla", "pallas"])
+@pytest.mark.parametrize("seed", [0, 1, 2])
+def test_split_layout_fixed_point_matches_ref(monkeypatch, kernels, seed):
+    """On a graph with split rows and a relay chain through a spilled slot,
+    the dispatched fixed point (XLA, or the Pallas kernel on the split
+    layout) equals the edge-list reference: booleans and rounds exact; the
+    chain's end reaches the hub in its four hops."""
+    monkeypatch.setenv("REPRO_UFA_KERNELS", kernels)
+    g, end = hub_graph(seed)
+    consts = edge_consts(g)
+    assert ("ell_tag" in consts) == (kernels == "1")
+    rng = np.random.default_rng(seed)
+    dark = rng.random((6, g.n)) < 0.05
+    dark[0] = False
+    dark[0, end] = True
+    got, rounds = fixed_point(jnp.asarray(dark), consts)
+    want, ref_rounds = ref_fixed_point(
+        jnp.asarray(dark), jnp.asarray(g.src), jnp.asarray(g.dst),
+        jnp.asarray(~g.fail_open))
+    assert np.array_equal(np.asarray(got), np.asarray(want))
+    assert int(rounds) == int(ref_rounds)
+    one, r1 = fixed_point(jnp.asarray(dark[:1]), consts)
+    assert np.asarray(one)[0, 0] and int(r1) >= 4 + 1
+    if kernels == "1":
+        # small blocks: aux chains cross row blocks, lanes cross blocks
+        small, r = fixed_point_ell(
+            jnp.asarray(dark), consts["ell_dst"], consts["ell_closed"],
+            consts["ell_tag"], block_s=2, block_r=8)
+        assert np.array_equal(np.asarray(small), np.asarray(want))
+        assert int(r) == int(ref_rounds)
+
+
+def test_harden_consts_on_split_rows(monkeypatch):
+    """The planner's in-place flips land on the edges' own rows, aux rows
+    included: the same consts as a rebuild from the hardened graph."""
+    monkeypatch.setenv("REPRO_UFA_KERNELS", "1")
+    g, _ = hub_graph(1)
+    consts = edge_consts(g)
+    row = np.asarray(consts["ell_row"])
+    closed = ~g.fail_open
+    pick = np.r_[np.flatnonzero(closed & (row >= g.n))[:5],
+                 np.flatnonzero(closed & (row < g.n))[:2]]
+    assert (row[pick] >= g.n).sum() == 5
+    got = harden_consts(consts, jnp.asarray(pick))
+    want = edge_consts(g.harden(pick))
+    for k in ("closed", "ell_closed", "ell_dst", "ell_row", "ell_tag"):
+        assert np.array_equal(np.asarray(got[k]), np.asarray(want[k])), k
+
+
+def test_paper_fleet_layout_unchanged(monkeypatch):
+    """The paper fleet (largest out-degree 13) keeps K = 16 and splits no
+    row: the consts are a straight ``ell_from_csr`` build, key for key."""
+    monkeypatch.setenv("REPRO_UFA_KERNELS", "1")
+    g = CallGraph.from_fleet_state(synthesize_fleet_state(scale=1.0, seed=7))
+    consts = edge_consts(g)
+    ell_dst, ell_closed, slot = ell_from_csr(g.n, g.indptr, g.dst,
+                                             ~g.fail_open)
+    assert ell_dst.shape == (g.n, 16)
+    assert set(consts) == {"src", "dst", "closed", "ell_dst", "ell_closed",
+                           "ell_slot"}
+    for k, v in (("ell_dst", ell_dst), ("ell_closed", ell_closed),
+                 ("ell_slot", slot)):
+        assert consts[k].dtype == v.dtype
+        assert np.array_equal(np.asarray(consts[k]), v), k
+    assert layout_counts(g) == {"edges": 120_827, "slots": g.n * 16,
+                                "split_rows": 0}
+
+
+def test_alibaba_fleet_layout_walks_few_slots(monkeypatch):
+    """The trace-shaped fleet at full size: a largest out-degree of 256 or
+    more, which the straight layout would walk 43 times over per edge or
+    more; the split layout walks at most 4 slots per edge."""
+    from repro.core.fleet_shapes import alibaba_fleet_state
+    monkeypatch.setenv("REPRO_UFA_KERNELS", "1")
+    g = CallGraph.from_fleet_state(alibaba_fleet_state(scale=1.0, seed=7))
+    deg = np.diff(g.indptr)
+    assert g.n == 21_979 and deg.max() >= 256
+    assert ell_slots(deg, -(-deg.max() // 8) * 8) >= 43 * g.n_edges
+    counts = layout_counts(g)
+    assert counts["edges"] == g.n_edges and counts["split_rows"] > 0
+    assert counts["slots"] <= 4 * g.n_edges
+
+
+def test_sweep_dependency_stage_on_split_layout(monkeypatch):
+    """The fused sweep's dependency stage over a small trace-shaped fleet:
+    the Pallas kernel on the split layout gives the XLA path's
+    broken-critical counts."""
+    from repro.core.fleet_shapes import alibaba_fleet_state
+    fs = alibaba_fleet_state(scale=0.05, seed=7, unsafe_chain_fraction=0.3)
+    graph = CallGraph.from_fleet_state(fs)
+    agg, cfg = FleetAggregates.from_fleet_state(fs), config_for_fleet(fs)
+    grid = scenario_grid(evict_fraction=(1.0, 0.6, 0.3, 0.1))
+    out = {}
+    for kernels in ("0", "1"):
+        monkeypatch.setenv("REPRO_UFA_KERNELS", kernels)
+        eng = SweepEngine(agg, cfg, graph=graph, ts=default_ts(7200.0, 24),
+                          reducer="scan")
+        assert ("ell_tag" in eng.dep) == (kernels == "1")
+        out[kernels] = eng.run(grid)
+    assert out["0"]["dep_n_broken_critical"].max() > 0
+    for k in ("dep_n_broken_critical", "dep_n_dark", "dep_broken_frac"):
+        assert np.array_equal(out["0"][k], out["1"][k]), k
 
 
 # ---------------------------------------------------------------------------
