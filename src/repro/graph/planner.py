@@ -20,13 +20,14 @@ from typing import Dict, List, Tuple
 
 import numpy as np
 
+import jax
 import jax.numpy as jnp
 
 from repro import obs
 from repro.graph.callgraph import CallGraph
 from repro.graph.propagation import (blast_radius, certify, edge_consts,
                                      fixed_point, harden_consts,
-                                     radius_counts)
+                                     radius_counts, radius_width)
 
 
 @dataclasses.dataclass
@@ -67,25 +68,47 @@ def plan_hardening(graph: CallGraph, batch: int = 64,
     the unweighted broken-critical count — only the greedy order changes;
     ``None`` keeps the historical ranking bit-identical.
 
-    The greedy loop is dispatch-hoisted: the edge/criticality arrays are
-    uploaded to the device once and the two jitted propagation closures
-    (full-blackhole certify, batched frontier blast radius) are reused
-    across rounds — only the fail-close mask changes, updated in place on
-    both sides.  Each round costs one (1, n) fixed point, one
-    bucket-padded (B, n) fixed point for the whole frontier, and (n,)/(B,)
-    transfers; nothing is re-traced and no (B, n) boolean matrix ever
-    crosses the host boundary.
+    The loop stays on the host, so each round's radius batch is as wide as
+    its frontier needs, but everything of size n or E stays on the
+    device: the edge consts, the dark set and the fail-close mask, which
+    is updated there.  A round is four jitted dispatches — the (1, n)
+    fixed point (the program certify runs), its verdict (the
+    broken-critical count and which of the plan's F fail-close edges form
+    the frontier), the radius batch of the frontier's callers, and the
+    mask update of the round's picks — and what crosses is a count, (F,)
+    frontier bools, (width,) caller indices and counts, and (batch,)
+    picks.  Ranking stays on the host in float64, so the plan is that of
+    the historical host loop, edge for edge.
     """
     with obs.span("ufa.planner.plan", batch=batch):
         return _plan_hardening(graph, batch, max_rounds, service_weights)
 
 
+@jax.jit
+def _frontier(broken: jnp.ndarray, closed: jnp.ndarray,
+              crit_live: jnp.ndarray, cand: jnp.ndarray,
+              cand_dst: jnp.ndarray, cand_live: jnp.ndarray):
+    """One round's verdict from its (1, n) fixed point ``broken``, on the
+    device: the count of broken live criticals, and which fail-close edges
+    ``cand`` form the frontier — still ``closed``, callee broken, caller
+    live (``cand_live``: hardening an edge whose caller is itself dark
+    changes nothing)."""
+    return (jnp.count_nonzero(broken[0] & crit_live),
+            closed[cand] & broken[0, cand_dst] & cand_live)
+
+
 def _plan_hardening(graph: CallGraph, batch: int, max_rounds: int,
                     service_weights) -> HardeningPlan:
     dark = np.asarray(graph.preemptible, bool)
-    crit_live = graph.critical & ~dark
-    closed = ~graph.fail_open.copy()           # host mirror of the mask
     consts = edge_consts(graph)                # backend-dispatched kernel
+    # every frontier edge is fail-close at the start; padded to whole
+    # buckets with repeats, whose verdicts the host drops
+    cand = np.flatnonzero(~graph.fail_open).astype(np.int32)
+    cand_pad = np.resize(cand, radius_width(len(cand)))
+    cand_d = jnp.asarray(cand_pad)
+    cand_dst_d = jnp.asarray(graph.dst[cand_pad])
+    cand_live_d = jnp.asarray(~dark[graph.src[cand_pad]])
+    crit_live_d = jnp.asarray(graph.critical & ~dark)
     crit_d = jnp.asarray(graph.critical)
     weights_d = (None if service_weights is None
                  else jnp.asarray(np.asarray(service_weights, np.float32)))
@@ -97,9 +120,12 @@ def _plan_hardening(graph: CallGraph, batch: int, max_rounds: int,
     while rounds < max_rounds:
         with obs.span("ufa.planner.round") as round_span:
             broken_d, _ = fixed_point(dark_d, consts)
-            broken = np.asarray(broken_d[0])
-            n_bc = int(np.count_nonzero(broken & crit_live))
-            round_span.set(broken_critical=n_bc)
+            n_bc, on_frontier = jax.device_get(_frontier(
+                broken_d, consts["closed"], crit_live_d, cand_d, cand_dst_d,
+                cand_live_d))
+            moved = n_bc.nbytes + on_frontier.nbytes
+            n_bc = int(n_bc)
+            round_span.set(broken_critical=n_bc, width=0, moved_bytes=moved)
             trajectory.append({"n_hardened": len(hardened),
                                "n_broken_critical": n_bc})
             obs.set_gauge("ufa_planner_broken_critical", n_bc)
@@ -108,11 +134,7 @@ def _plan_hardening(graph: CallGraph, batch: int, max_rounds: int,
                 break
             rounds += 1
             obs.inc("ufa_planner_rounds_total")
-            # frontier: fail-close edges relaying breakage into a live
-            # caller (hardening an edge whose caller is itself dark
-            # changes nothing)
-            frontier = np.flatnonzero(closed & broken[graph.dst]
-                                      & ~dark[graph.src])
+            frontier = cand[on_frontier[:len(cand)]]
             if len(frontier) == 0:
                 # a bare assert here vanished under ``python -O``, leaving
                 # the loop re-certifying the same stale state until
@@ -127,7 +149,7 @@ def _plan_hardening(graph: CallGraph, batch: int, max_rounds: int,
                     "disagree (inconsistent graph state?); hardening "
                     "cannot make progress")
             callers = np.unique(graph.src[frontier])
-            counts = radius_counts(callers, graph.n, consts, crit_d,
+            counts = radius_counts(callers, consts, crit_d,
                                    weights=weights_d)
             radius = np.zeros(graph.n, counts.dtype)
             radius[callers] = counts
@@ -137,11 +159,16 @@ def _plan_hardening(graph: CallGraph, batch: int, max_rounds: int,
             w = graph.weight[frontier].astype(np.float64)
             score += w / (w.max() + 1.0)
             pick = frontier[np.argsort(-score, kind="stable")[:batch]]
-            round_span.set(frontier=len(frontier), picked=len(pick))
-            obs.inc("ufa_planner_hardened_edges_total", int(len(pick)))
             hardened.extend(int(i) for i in pick)
-            closed[pick] = False
-            consts = harden_consts(consts, jnp.asarray(pick))
+            # padded to ``batch`` with repeats, so the update compiles once
+            picks = np.resize(pick, batch)
+            consts = harden_consts(consts, picks)
+            width = radius_width(len(callers))
+            # int32 caller indices up and their counts down, picks up
+            moved += width * (4 + counts.itemsize) + picks.nbytes
+            round_span.set(frontier=len(frontier), picked=len(pick),
+                           width=width, moved_bytes=moved)
+            obs.inc("ufa_planner_hardened_edges_total", int(len(pick)))
     g = graph.harden(hardened)
     if not certified:
         # ran out of rounds after a harden — the last cert is stale

@@ -81,19 +81,25 @@ def _fixed_point(dark: jnp.ndarray, src: jnp.ndarray, dst: jnp.ndarray,
     return broken, rounds
 
 
+def _one_hot(sources: jnp.ndarray, n: int) -> jnp.ndarray:
+    """(B,) service indices -> the (B, n) dark batch, one service a row."""
+    return sources[:, None] == jnp.arange(n, dtype=sources.dtype)[None, :]
+
+
 @jax.jit
-def _radius_kernel(dark: jnp.ndarray, consts: Dict[str, jnp.ndarray],
+def _radius_kernel(sources: jnp.ndarray, consts: Dict[str, jnp.ndarray],
                    crit: jnp.ndarray):
-    """Batched blast-radius counts: propagate the (B, n) dark batch to its
-    fixed point (backend-dispatched) and reduce to per-row
-    broken-critical counts *on device*, so only (B,) ints cross the host
-    boundary (the (B, n) broken matrix never does)."""
-    broken, _ = fixed_point(dark, consts)
+    """Batched blast-radius counts: darken each of the (B,) ``sources``
+    alone, propagate the (B, n) batch to its fixed point
+    (backend-dispatched) and reduce to per-row broken-critical counts *on
+    device*, so only (B,) ints cross the host boundary each way (the
+    (B, n) dark and broken matrices never do)."""
+    broken, _ = fixed_point(_one_hot(sources, crit.shape[0]), consts)
     return (broken & crit[None, :]).sum(axis=1).astype(jnp.int32)
 
 
 @jax.jit
-def _weighted_radius_kernel(dark: jnp.ndarray,
+def _weighted_radius_kernel(sources: jnp.ndarray,
                             consts: Dict[str, jnp.ndarray],
                             weights: jnp.ndarray):
     """Weighted blast radius: same fixed point, but each broken service
@@ -101,7 +107,7 @@ def _weighted_radius_kernel(dark: jnp.ndarray,
     availability-sensitivity weights turn the planner's edge ranking into
     a blast-*impact* ranking (weights are expected to already encode
     criticality, e.g. zero on non-critical services)."""
-    broken, _ = fixed_point(dark, consts)
+    broken, _ = fixed_point(_one_hot(sources, weights.shape[0]), consts)
     return (broken * weights[None, :]).sum(axis=1).astype(jnp.float32)
 
 
@@ -178,49 +184,69 @@ def edge_consts(graph: CallGraph) -> Dict[str, jnp.ndarray]:
     return out
 
 
+@jax.jit
+def _harden_masks(closed: jnp.ndarray, ell: Optional[tuple],
+                  pick: jnp.ndarray):
+    """``closed`` and, with ``ell = (ell_closed, rows, slots)``, its ELL
+    mirror with edges ``pick`` set fail-open (repeats are harmless)."""
+    closed = closed.at[pick].set(False)
+    if ell is None:
+        return closed, None
+    ell_closed, rows, slots = ell
+    return closed, ell_closed.at[rows[pick], slots[pick]].set(False)
+
+
 def harden_consts(consts: Dict[str, jnp.ndarray],
                   pick: jnp.ndarray) -> Dict[str, jnp.ndarray]:
     """Convert edges ``pick`` (CSR indices) to fail-open in the device
     consts — both the edge-list mask and, when present, its ELL mirror,
     at each edge's own row (an aux row where its caller's row is split) —
-    without re-uploading anything else (the planner's per-round update).
-    """
-    out = dict(consts, closed=consts["closed"].at[pick].set(False))
+    in one jitted update that re-uploads nothing else (the planner's
+    per-round update, whose ``pick`` is padded to ``batch`` with repeats
+    so that it compiles once)."""
+    ell = None
     if "ell_closed" in consts:
-        rows = consts.get("ell_row", consts["src"])
-        out["ell_closed"] = consts["ell_closed"].at[
-            rows[pick], consts["ell_slot"][pick]].set(False)
+        ell = (consts["ell_closed"], consts.get("ell_row", consts["src"]),
+               consts["ell_slot"])
+    closed, ell_closed = _harden_masks(consts["closed"], ell, pick)
+    out = dict(consts, closed=closed)
+    if ell is not None:
+        out["ell_closed"] = ell_closed
     return out
 
 
-def radius_counts(sources: np.ndarray, n: int,
-                  consts: Dict[str, jnp.ndarray], crit_d,
-                  weights=None) -> np.ndarray:
+def radius_width(k: int) -> int:
+    """Rows of the bucket-padded batches that sweep ``k`` sources: ``k``
+    rounded up to a multiple of _BUCKET (a full _CHUNK is whole
+    buckets)."""
+    return _BUCKET * -(-k // _BUCKET)
+
+
+def radius_counts(sources: np.ndarray, consts: Dict[str, jnp.ndarray],
+                  crit_d, weights=None) -> np.ndarray:
     """Blast-radius counts for ``sources`` against device-resident edge
     consts (``edge_consts``) — the reusable closure the hardening planner
     calls once per greedy round (the device arrays are uploaded once, not
-    per call).  Sources are swept in bucket-padded batches (multiples of
-    _BUCKET up to _CHUNK) through the jitted kernel; returns counts
-    aligned with ``sources``.
+    per call).  Sources are swept in bucket-padded batches
+    (``radius_width``, at most _CHUNK rows each) through the jitted
+    kernel, which builds each batch's one-hot dark rows itself: a batch
+    crosses as (width,) int32 indices and comes back as (width,) counts.
+    Returns counts aligned with ``sources``.
 
     ``weights`` (optional, device-resident (n,) f32): rank by *weighted*
     blast radius — the sum of per-service weights over the broken set —
     instead of the unweighted broken-critical count.  ``None`` keeps the
     historical integer counts bit-identical."""
-    sources = np.asarray(sources, np.int64)
+    sources = np.asarray(sources, np.int32)
     out = np.zeros(len(sources), np.int32 if weights is None else np.float32)
     for lo in range(0, len(sources), _CHUNK):
         chunk = sources[lo:lo + _CHUNK]
-        width = min(_CHUNK, _BUCKET * -(-len(chunk) // _BUCKET))
-        pad = np.full(width, chunk[-1], np.int64)
+        pad = np.full(radius_width(len(chunk)), chunk[-1], np.int32)
         pad[:len(chunk)] = chunk
-        dark = np.zeros((width, n), bool)
-        dark[np.arange(width), pad] = True
         if weights is None:
-            counts = _radius_kernel(jnp.asarray(dark), consts, crit_d)
+            counts = _radius_kernel(pad, consts, crit_d)
         else:
-            counts = _weighted_radius_kernel(jnp.asarray(dark), consts,
-                                             weights)
+            counts = _weighted_radius_kernel(pad, consts, weights)
         out[lo:lo + len(chunk)] = np.asarray(counts)[:len(chunk)]
     return out
 
@@ -388,7 +414,7 @@ def blast_radius(graph: CallGraph,
     out = np.zeros(graph.n, np.int32)
     if len(sources) == 0:
         return out
-    out[sources] = radius_counts(sources, graph.n, edge_consts(graph),
+    out[sources] = radius_counts(sources, edge_consts(graph),
                                  jnp.asarray(graph.critical))
     return out
 

@@ -2,6 +2,8 @@
 blackhole ensembles, the hardening planner, the regression gate, and the
 drills/scenarios integration."""
 
+import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
@@ -13,7 +15,9 @@ from repro.core.service import synthesize_fleet, unsafe_edges
 from repro.graph import (CallGraph, blackhole_ensemble, blast_radius,
                          certify, plan_hardening, propagate, propagate_many,
                          regression_gate)
+from repro.graph import planner, propagation
 from repro.graph.callgraph import _build_csr
+from repro.graph.propagation import edge_consts, fixed_point
 
 
 # ---------------------------------------------------------------------------
@@ -158,6 +162,194 @@ def test_planner_hardens_until_certified():
     # relay chains mean certification needs fewer conversions than there
     # are unsafe edges (chains die once their entry edges are hardened)
     assert plan.n_hardened < g.n_unsafe
+
+
+# ---------------------------------------------------------------------------
+# reference planner: the greedy loop with every round on the host
+# ---------------------------------------------------------------------------
+
+
+@jax.jit
+def _ref_radius(dark, consts, crit):
+    broken, _ = fixed_point(dark, consts)
+    return (broken & crit[None, :]).sum(axis=1).astype(jnp.int32)
+
+
+@jax.jit
+def _ref_weighted_radius(dark, consts, weights):
+    broken, _ = fixed_point(dark, consts)
+    return (broken * weights[None, :]).sum(axis=1).astype(jnp.float32)
+
+
+def _ref_radius_counts(sources, n, consts, crit_d, weights_d):
+    """Blast radius of each source from host-built (width, n) one-hot
+    batches, padded as the planner pads them."""
+    out = np.zeros(len(sources),
+                   np.int32 if weights_d is None else np.float32)
+    for lo in range(0, len(sources), 512):
+        chunk = sources[lo:lo + 512]
+        width = min(512, 128 * -(-len(chunk) // 128))
+        pad = np.full(width, chunk[-1], np.int64)
+        pad[:len(chunk)] = chunk
+        dark = np.zeros((width, n), bool)
+        dark[np.arange(width), pad] = True
+        counts = (_ref_radius(jnp.asarray(dark), consts, crit_d)
+                  if weights_d is None else
+                  _ref_weighted_radius(jnp.asarray(dark), consts,
+                                       weights_d))
+        out[lo:lo + len(chunk)] = np.asarray(counts)[:len(chunk)]
+    return out
+
+
+def _ref_harden(consts, pick):
+    pick = jnp.asarray(pick)
+    out = dict(consts, closed=consts["closed"].at[pick].set(False))
+    if "ell_closed" in consts:
+        rows = consts.get("ell_row", consts["src"])
+        out["ell_closed"] = consts["ell_closed"].at[
+            rows[pick], consts["ell_slot"][pick]].set(False)
+    return out
+
+
+def reference_plan(graph, batch=64, max_rounds=10_000,
+                   service_weights=None):
+    """``plan_hardening`` with the broken set, the frontier, the one-hot
+    radius batches and the mask on the host each round: the plan's
+    ``(hardened_edges, trajectory, certified, rounds)``."""
+    dark = np.asarray(graph.preemptible, bool)
+    crit_live = graph.critical & ~dark
+    closed = ~graph.fail_open.copy()
+    consts = edge_consts(graph)
+    crit_d = jnp.asarray(graph.critical)
+    weights_d = (None if service_weights is None
+                 else jnp.asarray(np.asarray(service_weights, np.float32)))
+    dark_d = jnp.asarray(dark[None, :])
+    hardened, trajectory, rounds, certified = [], [], 0, False
+    while rounds < max_rounds:
+        broken_d, _ = fixed_point(dark_d, consts)
+        broken = np.asarray(broken_d[0])
+        n_bc = int(np.count_nonzero(broken & crit_live))
+        trajectory.append({"n_hardened": len(hardened),
+                           "n_broken_critical": n_bc})
+        if n_bc == 0:
+            certified = True
+            break
+        rounds += 1
+        frontier = np.flatnonzero(closed & broken[graph.dst]
+                                  & ~dark[graph.src])
+        if len(frontier) == 0:
+            raise RuntimeError("reference stalled: no fail-close frontier")
+        callers = np.unique(graph.src[frontier])
+        counts = _ref_radius_counts(callers, graph.n, consts, crit_d,
+                                    weights_d)
+        radius = np.zeros(graph.n, counts.dtype)
+        radius[callers] = counts
+        score = radius[graph.src[frontier]].astype(np.float64)
+        w = graph.weight[frontier].astype(np.float64)
+        score += w / (w.max() + 1.0)
+        pick = frontier[np.argsort(-score, kind="stable")[:batch]]
+        hardened.extend(int(i) for i in pick)
+        closed[pick] = False
+        consts = _ref_harden(consts, pick)
+    if not certified:
+        certified = certify(graph.harden(hardened), dark).ok
+    return hardened, trajectory, certified, rounds
+
+
+def _paper_graph():
+    return CallGraph.from_fleet_state(synthesize_fleet_state(
+        scale=0.1, seed=7, unsafe_chain_fraction=0.06))
+
+
+def _alibaba_graph():
+    from repro.core.fleet_shapes import alibaba_fleet_state
+    return CallGraph.from_fleet_state(alibaba_fleet_state(scale=0.05,
+                                                          seed=7))
+
+
+def _weights(g):
+    return np.random.default_rng(3).random(g.n) * g.critical
+
+
+@pytest.mark.parametrize("case,kernels,batch,max_rounds,weighted", [
+    ("paper", "0", 4, 10_000, False),
+    ("alibaba", "1", 4, 10_000, False),
+    ("weighted", "0", 4, 10_000, True),
+    ("cut_short", "0", 4, 1, False),
+])
+def test_planner_matches_host_loop(monkeypatch, case, kernels, batch,
+                                   max_rounds, weighted):
+    """The planner whose rounds stay on the device gives the host loop's
+    plan in every field: on the paper-shaped fleet, on a trace-shaped one
+    (hub rows split in the kernel's layout), on the weighted ranking, and
+    cut short by ``max_rounds`` (certification then re-runs on the plan)."""
+    monkeypatch.setenv("REPRO_UFA_KERNELS", kernels)
+    g = _alibaba_graph() if case == "alibaba" else _paper_graph()
+    w = _weights(g) if weighted else None
+    plan = plan_hardening(g, batch=batch, max_rounds=max_rounds,
+                          service_weights=w)
+    hardened, trajectory, certified, rounds = reference_plan(
+        g, batch=batch, max_rounds=max_rounds, service_weights=w)
+    assert plan.hardened_edges == hardened
+    assert plan.trajectory == trajectory
+    assert (plan.certified, plan.rounds) == (certified, rounds)
+    assert plan.hardened_edge_names == g.edge_names(hardened)
+    assert np.array_equal(plan.graph.fail_open, g.harden(hardened).fail_open)
+    if case == "cut_short":
+        assert rounds == 1 and not certified
+    else:
+        assert certified and rounds > 1
+
+
+def test_planner_stall_raises(monkeypatch):
+    """Broken criticals whose only fail-close edge has a dark caller: the
+    frontier is empty from the first round (hardening that edge changes
+    nothing), and the planner raises instead of spinning, as the host loop
+    does."""
+    # 0 critical -> 1 preemptible fail-open; 2 preemptible -> 1 fail-close
+    g = _build_csr(3, np.array([0, 2], np.int32), np.array([1, 1], np.int32),
+                   np.array([True, False]), np.ones(2, np.float32),
+                   np.array([True, False, False]),
+                   np.array([False, True, True]), ["crit", "pre", "pre2"])
+    def everything_breaks(dark, consts):
+        return jnp.ones_like(dark), jnp.asarray(0)
+
+    monkeypatch.setattr(planner, "fixed_point", everything_breaks)
+    monkeypatch.setitem(globals(), "fixed_point", everything_breaks)
+    with pytest.raises(RuntimeError, match="after 0 hardened edge"):
+        plan_hardening(g)
+    with pytest.raises(RuntimeError, match="no fail-close"):
+        reference_plan(g)
+
+
+def test_planner_compiles_once_per_graph():
+    """A second plan on the same graph adds no entry to the jit caches of
+    a round's programs: the fixed point and its verdict, the radius batch
+    and the mask update."""
+    g = _paper_graph()
+    programs = (propagation._fixed_point, planner._frontier,
+                propagation._radius_kernel, propagation._harden_masks)
+    first = plan_hardening(g, batch=16)
+    sizes = [p._cache_size() for p in programs]
+    assert min(sizes) >= 1
+    second = plan_hardening(g, batch=16)
+    assert [p._cache_size() for p in programs] == sizes
+    assert second.hardened_edges == first.hardened_edges
+
+
+@pytest.mark.parametrize("k", [1, 128, 513])
+def test_blast_radius_widths_match_bfs(k):
+    """Source batches of one row, one whole bucket, and a full chunk plus
+    one: the radius from index batches equals the BFS reference."""
+    rng = np.random.default_rng(k)
+    g, edges = random_graph(rng, n=600, p_edge=0.004, p_close=0.6)
+    sources = np.sort(rng.choice(g.n, size=k, replace=False))
+    radius = blast_radius(g, sources=sources)
+    want = np.zeros(g.n, np.int64)
+    for j in sources:
+        want[j] = sum(g.critical[u]
+                      for u in bfs_propagate(g.n, edges, [j]))
+    assert np.array_equal(radius, want)
 
 
 def test_regression_gate_flags_planted_edge():

@@ -151,6 +151,14 @@ def test_planner_spans_nest_with_counts(traced):
     assert all(r[4]["picked"] <= 4 and r[4]["frontier"] >= r[4]["picked"]
                for r in rounds[:-1])
     assert "picked" not in rounds[-1][4]
+    # only indices and counts cross: no (width, n) dark matrix, and no
+    # (n,) broken vector
+    n = plan.graph.n
+    for r in rounds[:-1]:
+        assert r[4]["width"] > 0 and r[4]["width"] % 128 == 0
+        assert 0 < r[4]["moved_bytes"] < r[4]["width"] * n
+    assert rounds[-1][4]["width"] == 0
+    assert 0 < rounds[-1][4]["moved_bytes"] < n
 
 
 def test_graph_spans_count_the_layout(tmp_path, monkeypatch):
