@@ -12,7 +12,7 @@ verdicts refine every bracket at once.
 Localizing a frontier to severity resolution ``tol`` costs
 ``~log2(1/tol)`` engine evaluations per ray instead of the
 ``1/tol + 1`` an exhaustive grid at the same resolution needs — the
-bench asserts the >=10x saving on the paper-scale fleet.
+tests assert the >=10x saving on the hardened paper-shaped fleet.
 
 Every probe's verdict row is logged so ``report.verify_report`` can
 re-evaluate the whole campaign on a fresh engine and assert the
@@ -320,8 +320,7 @@ def campaign_for_fleet(fs, *, seed: int = 0, with_graph: bool = True,
     beyond the engine's; the report records the same seed.
 
     The fleet is placed by a fresh ``Orchestrator`` (steady state) so
-    the engine sees post-placement pool occupancy, exactly like the
-    fused-sweep bench.
+    the engine sees post-placement pool occupancy.
     """
     from repro.core.capacity import RegionCapacity
     from repro.core.omg import Orchestrator

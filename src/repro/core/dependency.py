@@ -535,8 +535,6 @@ def _runtime_analysis(fleet, n_records, seed, chunk_records):
         edges = trace_edges(fleet, seed=seed)
         if edges is not None:
             det = RuntimeFailCloseDetector(edges=edges)
-            # gen_ingest_s counts the alias tables, as it always has
-            t0 = time.perf_counter()
             edges.sampling_tables()
             tables.set(edges=edges.n)
     is_arrays = isinstance(fleet, FleetState)
@@ -552,7 +550,6 @@ def _runtime_analysis(fleet, n_records, seed, chunk_records):
                 "cold_paths": set(), "true_positives": 0,
                 "false_positives": 0, "missed": 0, "missed_cold": 0,
                 "precision": 0.0, "recall": 0.0, "n_records": 0,
-                "gen_ingest_s": 0.0, "records_per_s": 0.0,
                 "detector": RuntimeFailCloseDetector()}
     if n_records is None:
         n_records = 400 * max(1, edges.n)
@@ -567,7 +564,6 @@ def _runtime_analysis(fleet, n_records, seed, chunk_records):
         pending = chunk
     if pending is not None:
         det.ingest_batch(*pending)
-    gen_ingest_s = time.perf_counter() - t0
 
     with obs.span("ufa.detect.mask"):
         mask = det.detect_mask()
@@ -594,7 +590,5 @@ def _runtime_analysis(fleet, n_records, seed, chunk_records):
         "precision": len(tp) / max(1, len(found)),
         "recall": len(tp) / max(1, len(truth)),
         "n_records": n_records,
-        "gen_ingest_s": gen_ingest_s,
-        "records_per_s": n_records / max(1e-9, gen_ingest_s),
         "detector": det,
     }

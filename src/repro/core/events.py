@@ -1,7 +1,7 @@
 """Minimal deterministic discrete-event simulation kernel.
 
-OMG orchestration, drills and the failover benchmarks all run on this: a
-priority queue of (time, seq, fn) with a monotonically advancing clock.
+OMG orchestration and the drills run on this: a priority queue of
+(time, seq, fn) with a monotonically advancing clock.
 
 Observability: attaching a ``repro.obs.Tracer`` (``loop.tracer = t``, or
 ``Orchestrator(..., tracer=t)``) turns every fired event into a sim-time

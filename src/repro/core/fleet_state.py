@@ -105,12 +105,6 @@ class FleetState:
             m = m & (self.placement == PLACEMENT_CODE[placement])
         return float(self.cores_live[m].sum())
 
-    def class_envs(self, fc, placement: str) -> int:
-        m = (self.class_mask(fc)
-             & (self.placement == PLACEMENT_CODE[placement])
-             & (self.replicas_live > 0))
-        return int(np.count_nonzero(m))
-
     def class_core_totals(self) -> Tuple[float, float, float, float]:
         """(always_on, active_migrate, restore_later, terminate) spec cores."""
         cores = self.spec_cores

@@ -281,9 +281,6 @@ class Orchestrator:
                     ) -> float:
         return self.fs.class_cores(fc, placement)
 
-    def class_envs(self, fc: FailureClass, placement: str) -> int:
-        return self.fs.class_envs(fc, placement)
-
     def _snap(self, **extra):
         fs = self.fs
         burst = (self.region.batch.burst.used

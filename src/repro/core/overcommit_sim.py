@@ -100,8 +100,8 @@ def recommend_factor(cfg: OvercommitSimConfig = OvercommitSimConfig(),
     The result carries an explicit ``safe`` flag: when NO factor on the
     grid clears the violation budget and the O_max bound, ``recommended``
     falls back to ``grid_lo`` *without* implying it is safe — callers
-    (the capacity planner example, the overcommit bench) must check
-    ``safe`` before acting on the recommendation."""
+    (the capacity planner example) must check ``safe`` before acting on
+    the recommendation."""
     factors = factor_grid(grid_lo, grid_hi, grid_step)
     rates = np.asarray(_grid_violation_rates(cfg, jnp.asarray(factors)))
     omax = o_max()

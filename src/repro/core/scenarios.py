@@ -471,15 +471,3 @@ def summarize_sweep(result: Dict[str, np.ndarray]) -> Dict[str, object]:
         out["t_peak_cloud_cores_max"] = float(
             result["t_peak_cloud_cores"].max())
     return out
-
-
-def scenario_records(result: Dict[str, np.ndarray]) -> list:
-    """Per-scenario verdict rows (JSON-serializable) for the bench log."""
-    keys = ["traffic_mult", "burst_delay_s", "burst_availability",
-            "cloud_quota_frac", "overcommit_factor", "evict_fraction",
-            "burst_full_s", "rl_done_s", "util_peak", "availability",
-            "ao_ok", "rl_ok", "util_ok", "sla_ok"]
-    n = len(result["sla_ok"])
-    return [{k: (bool(result[k][i]) if result[k].dtype == bool
-                 else round(float(result[k][i]), 6)) for k in keys}
-            for i in range(n)]

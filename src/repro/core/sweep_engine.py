@@ -258,8 +258,8 @@ def _run_chunks_dep(consts, dep, pchunks, invchunks, storm_invchunks,
 
 def compiled_variants() -> int:
     """Number of compiled pipeline programs (jit cache entries across both
-    entry points) — the scale bench asserts this does not grow across
-    grid sizes within a padding bucket."""
+    entry points) — the tests assert this does not grow across grid
+    sizes within a padding bucket."""
     return int(_run_chunks._cache_size() + _run_chunks_dep._cache_size())
 
 
@@ -528,6 +528,6 @@ def fused_sweep(fs, grid: Optional[Dict[str, np.ndarray]] = None, *,
 
 def tile_grid(grid: Dict[str, np.ndarray], n: int) -> Dict[str, np.ndarray]:
     """Tile a scenario grid out to ``n`` rows (cycling the base grid) —
-    the scale benches use this to sweep {256 .. 100k+} scenario counts
-    with the paper's axes."""
+    the examples and ``chip_smoke.py`` use this to sweep {256 .. 100k+}
+    scenario counts with the paper's axes."""
     return {k: np.resize(np.asarray(v), n) for k, v in grid.items()}

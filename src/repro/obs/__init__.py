@@ -143,13 +143,10 @@ CATALOG: Dict[str, Tuple[str, str, Tuple[str, ...],
     "ufa_serving_queue_depth": (
         "gauge", "scheduler queue depth at the latest drill step",
         ("tier",), None),
-    # -- profiler / bench -----------------------------------------------
+    # -- profiler -------------------------------------------------------
     "ufa_phase_seconds": (
         "histogram", "wall time of named pipeline phases", ("phase",),
         None),
-    "ufa_bench_us_per_call": (
-        "gauge", "benchmark harness rows (microseconds per call)",
-        ("name",), None),
 }
 
 

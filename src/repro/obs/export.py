@@ -1,7 +1,7 @@
 """Prometheus text exposition + JSONL snapshots (and their validators).
 
 One ``Registry.collect()`` snapshot feeds both exporters, so live
-metrics, bench rows and CI artifacts share a single source of truth:
+metrics and CI artifacts share a single source of truth:
 
   * :func:`to_prometheus` — the text exposition format (version 0.0.4):
     ``# HELP`` / ``# TYPE`` headers, escaped label values, cumulative

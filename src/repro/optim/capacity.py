@@ -510,7 +510,7 @@ def verify_design(design, grid: Optional[Dict[str, np.ndarray]] = None,
     """Run the optimized design through the REAL hard pipeline (an
     actual ``SweepEngine``, optionally with the dependency stage) over
     the certification ensemble; returns the pass counts + availability
-    floor the bench asserts on."""
+    floor the tests assert on."""
     grid = certification_grid() if grid is None else grid
     timeline, agg, extra = design_timeline(design)
     eng = SweepEngine(agg, timeline, graph=graph, seed=seed,

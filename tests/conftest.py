@@ -2,86 +2,6 @@
 # and benches must see 1 device (the 512-device mesh exists only inside
 # launch/dryrun.py and the subprocess-based elastic/sharding tests).
 import os
-import random
 import sys
-import types
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
-
-# ---------------------------------------------------------------------------
-# hypothesis guard: the property tests require hypothesis (see
-# requirements-test.txt).  When it is missing we install a tiny
-# deterministic stand-in — @given draws a fixed number of pseudo-random
-# examples — so the suite still runs (with reduced case diversity) instead
-# of dying at collection.
-# ---------------------------------------------------------------------------
-try:
-    import hypothesis  # noqa: F401
-except ImportError:
-    def _make_stub():
-        mod = types.ModuleType("hypothesis")
-        st = types.ModuleType("hypothesis.strategies")
-        mod.__version__ = "0.0-stub"
-
-        class _Strategy:
-            def __init__(self, gen):
-                self.gen = gen
-
-        def integers(min_value=0, max_value=100):
-            return _Strategy(lambda rng: rng.randint(int(min_value),
-                                                     int(max_value)))
-
-        def floats(min_value=0.0, max_value=1.0, **_kw):
-            return _Strategy(lambda rng: rng.uniform(float(min_value),
-                                                     float(max_value)))
-
-        def lists(elements, min_size=0, max_size=10, **_kw):
-            def gen(rng):
-                n = rng.randint(min_size, max_size)
-                return [elements.gen(rng) for _ in range(n)]
-            return _Strategy(gen)
-
-        def booleans():
-            return _Strategy(lambda rng: bool(rng.randint(0, 1)))
-
-        def sampled_from(seq):
-            seq = list(seq)
-            return _Strategy(lambda rng: rng.choice(seq))
-
-        def given(**strategies):
-            def deco(fn):
-                n_examples = getattr(fn, "_stub_max_examples", 10)
-
-                # NOTE: no functools.wraps — pytest must see a zero-arg
-                # signature, not the original parametrized one
-                def run():
-                    rng = random.Random(0)
-                    for _ in range(n_examples):
-                        drawn = {k: s.gen(rng)
-                                 for k, s in strategies.items()}
-                        fn(**drawn)
-                run.__name__ = fn.__name__
-                run.__doc__ = fn.__doc__
-                run.__module__ = fn.__module__
-                return run
-            return deco
-
-        def settings(max_examples=10, **_kw):
-            def deco(fn):
-                fn._stub_max_examples = max_examples
-                return fn
-            return deco
-
-        st.integers = integers
-        st.floats = floats
-        st.lists = lists
-        st.booleans = booleans
-        st.sampled_from = sampled_from
-        mod.strategies = st
-        mod.given = given
-        mod.settings = settings
-        mod.HealthCheck = types.SimpleNamespace(all=lambda: [])
-        sys.modules["hypothesis"] = mod
-        sys.modules["hypothesis.strategies"] = st
-
-    _make_stub()

@@ -186,6 +186,17 @@ def test_optimizer_cem_improves_and_verifies(fs):
     assert v["n_t_sla_ok"] == v["n_scenarios"], v
 
 
+def test_optimizer_both_modes_hold_availability_floor(fs):
+    """Grad anneal then CEM polish: the certified optimum keeps every
+    scenario's analytic availability at the 99.97 % floor."""
+    res = optimize_capacity(fs, mode="both")
+    assert res.improved
+    assert res.provisioning_multiple <= 1.4
+    v = res.verification
+    assert v["all_ok"], v
+    assert v["availability_min"] >= 0.9997 - 1e-9, v["availability_min"]
+
+
 def test_hand_tuned_ufa_design_verifies(fs):
     """The paper's hand-tuned operating point passes the certification
     ensemble through the real hard engine — the optimizer's constraint

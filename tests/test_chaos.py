@@ -62,6 +62,15 @@ def test_ray_validation():
 # correlated sampler (property tests)
 # ---------------------------------------------------------------------------
 
+@pytest.fixture(scope="module", autouse=True)
+def _compiled_sampler():
+    """Compile the jitted copula once per draw shape the property tests
+    below use, so that compilation is not timed as one of hypothesis's
+    examples against its deadline."""
+    sample_faults(0, 1024, max_severity=0.8)
+    sample_faults(0, 2048)
+
+
 @given(seed=st.integers(min_value=0, max_value=2**20))
 @settings(max_examples=5)
 def test_sampler_marginals_and_reproducibility(seed):
@@ -439,3 +448,34 @@ def test_campaign_on_engine_reproducible_and_reverifiable(small_fleet):
     with pytest.raises(AssertionError):
         verify_report(rep, fresh.engine)
     tampered[key] = orig
+
+
+def test_campaign_default_rays_beat_grid_tenfold(small_fleet):
+    """On the hardened fleet at 1/64 severity resolution the default rays
+    localize at least three frontiers with at least ten times fewer
+    engine evaluations than a per-ray grid; the metered evaluation count
+    is the report's, and every probe replays on a fresh engine."""
+    from repro import obs
+    from repro.graph import CallGraph
+    from repro.graph.planner import plan_hardening
+
+    graph = CallGraph.from_fleet_state(small_fleet)
+    plan = plan_hardening(graph)
+    small_fleet.edges.fail_open[
+        graph.input_edge_indices(plan.hardened_edges)] = True
+
+    tol = 1.0 / 64.0
+    obs.enable().reset()
+    try:
+        rep = campaign_for_fleet(small_fleet, seed=7, tol=tol).run()
+        evals_metered = obs.value("ufa_chaos_evals_total")
+    finally:
+        obs.disable()
+    assert evals_metered == rep.n_evals
+    assert rep.op_ok
+    assert rep.n_localized >= 3, [(r.name, r.status) for r in rep.rays]
+    assert rep.speedup_vs_grid is not None and rep.speedup_vs_grid >= 10.0
+
+    fresh = campaign_for_fleet(small_fleet, seed=7, tol=tol)
+    out = verify_report(rep, fresh.engine)
+    assert out["n_probes"] == rep.n_evals and not out["mismatches"]

@@ -100,10 +100,11 @@ def test_default_registry_helpers_free_when_off(default_obs):
 
 
 def test_helpers_allow_label_literally_named_name(default_obs):
-    # ufa_bench_us_per_call's label IS "name" — the helpers take their
+    # a gauge whose label IS "name" — the helpers take their
     # metric-name/value arguments positional-only so this cannot collide
-    obs.set_gauge("ufa_bench_us_per_call", 12.5, name="row_a")
-    assert obs.value("ufa_bench_us_per_call", name="row_a") == 12.5
+    default_obs.gauge("t_rows", labels=("name",))
+    obs.set_gauge("t_rows", 12.5, name="row_a")
+    assert obs.value("t_rows", name="row_a") == 12.5
 
 
 def test_registry_thread_reentrancy():

@@ -387,6 +387,23 @@ def test_sweep_timeline_256_scenarios_under_5s():
     assert np.isinf(ttr[:, int(Tier.NP)]).all()
 
 
+def test_sweep_operating_point_agrees_with_orchestrator():
+    """The temporal sweep's operating-point scenario and the event-driven
+    orchestrator's single trajectory restore Restore-Later at the same
+    time, to within a minute or 5 %."""
+    fs = synthesize_fleet(scale=0.05, seed=7, as_arrays=True)
+    fs.apply_ufa_target_classes()
+    orch = Orchestrator(fs, RegionCapacity.for_fleet("timeline", fs))
+    cfg = orch.timeline_config()
+    grid = scenario_grid()
+    res = sweep_timeline(cfg, grid, default_ts(7200.0, 240))
+    rep = orch.failover(tv_failover=1.0)
+    op_rl_done = float(res["rl_done_s"][operating_point_mask(grid)][0])
+    assert abs(op_rl_done - rep.rl_restored_at_s) <= max(
+        60.0, 0.05 * rep.rl_restored_at_s), (op_rl_done,
+                                             rep.rl_restored_at_s)
+
+
 def test_config_for_fleet_is_side_effect_free():
     """Extracting a config must not disturb the caller's region pool
     counters or a FleetState's pool column — and re-extracting from a
